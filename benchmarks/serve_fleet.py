@@ -311,7 +311,7 @@ def _real_fleet_run(args, mix_spec: str, *, page_size: int | None,
     cfg = get_smoke_config("musicgen-large")
     lm = LM(cfg)
     rt = lm.runtime(ParallelConfig(attn_q_chunk=16, attn_kv_chunk=16))
-    params = lm.init(jax.random.key(0))[0]
+    params = jax.jit(lambda k: lm.init(k)[0])(jax.random.key(0))
     engine = Engine(lm, params, rt, max_batch=REAL_MAX_BATCH,
                     max_len=REAL_MAX_LEN, page_size=page_size)
     adapter = JaxEngineAdapter(engine, seed=seed)
@@ -488,6 +488,8 @@ def main(argv=None) -> dict:
         args.period = 3600.0
 
     if args.real:
+        from repro.launch.compile_cache import enable_compile_cache
+        enable_compile_cache()
         args.workflows = min(args.workflows, 4)
         out = run_real_fleet(args)
         with open(args.out, "w") as fh:

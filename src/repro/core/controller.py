@@ -26,6 +26,11 @@ strictly before the boundary they precede; scans come last):
      checkpoints, rebuilds the mesh with a new ``data``-axis extent,
      re-places the state (checkpoints are sharding-agnostic) and resumes;
      injected preemptions are absorbed by restart-from-latest-checkpoint.
+
+Every running job holds its own devices: indices into ``devices`` that no
+other running job holds, taken at launch and on ``grow`` and returned on
+``shrink`` and at finish. A one-node job runs on its one device (as the
+default device of its segment), never on the process default.
 """
 from __future__ import annotations
 
@@ -61,7 +66,9 @@ class TrainTask:
     runtime: float | None = None
     # ---- runtime state ----
     steps_done: int = 0
-    alloc: int = 0                    # devices currently assigned
+    devices: list = field(default_factory=list)  # indices it holds
+    # one loss per committed step: a preempted segment's losses are
+    # rolled back with its steps
     losses: list = field(default_factory=list)
     resizes: int = 0
     restarts: int = 0
@@ -69,6 +76,11 @@ class TrainTask:
     @property
     def done(self) -> bool:
         return self.steps_done >= self.num_steps
+
+    @property
+    def alloc(self) -> int:
+        """Devices currently assigned."""
+        return len(self.devices)
 
 
 class ElasticController:
@@ -89,6 +101,7 @@ class ElasticController:
         self.running: list[TrainTask] = []
         self.finished: list[TrainTask] = []
         self._done_last_tick: list[TrainTask] = []
+        self._free_devices = list(range(len(self.devices)))
 
     # ----------------------------------------------------------- plumbing
     @property
@@ -122,48 +135,67 @@ class ElasticController:
         self.env.submit(task)
 
     def _launch(self, task: TrainTask) -> None:
-        task.alloc = task.nodes
+        task.devices = self._take_devices(task.nodes)
         self.running.append(task)
 
-    def _mesh_for(self, n: int):
-        if n <= 1:
-            return None
-        # guarded raise, not assert: a mesh wider than the device pool
-        # must fail loudly (under ``python -O`` jax would raise a shape
-        # error much later, far from the sizing bug)
-        if n > len(self.devices):
+    def _take_devices(self, n: int) -> list[int]:
+        # guarded raise, not assert: the env's node count and the free
+        # device list must agree; under ``python -O`` a short list would
+        # otherwise put two jobs on one device
+        if n > len(self._free_devices):
             raise RuntimeError(
-                f"mesh wider than device pool: {n} > {len(self.devices)}")
+                f"mesh wider than device pool: {n} devices wanted, "
+                f"{len(self._free_devices)} of {len(self.devices)} free")
+        taken = self._free_devices[:n]
+        del self._free_devices[:n]
+        return taken
+
+    def _return_devices(self, idx: list[int]) -> None:
+        self._free_devices = sorted(self._free_devices + idx)
+
+    def devices_of(self, task: TrainTask) -> list:
+        """The devices ``task`` holds, in mesh order."""
+        return [self.devices[i] for i in task.devices]
+
+    def _mesh_for(self, devices: list):
+        if len(devices) <= 1:
+            return None
         from jax.sharding import Mesh
         from repro.parallel.sharding import AXIS_DATA
-        return Mesh(np.array(self.devices[:n]), (AXIS_DATA,))
+        return Mesh(np.array(devices), (AXIS_DATA,))
 
     # ------------------------------------------------------------- a tick
     def _run_segment(self, task: TrainTask, fail: bool = False) -> None:
-        """Run ``steps_per_tick`` steps of a task under its current mesh."""
-        mesh = self._mesh_for(task.alloc)
-        lm = LM(task.rcfg.model)
-        step_fn, rt, opt = build_train_step(lm, task.rcfg, mesh)
-        jit_step = jax.jit(step_fn, donate_argnums=(0,))
-        start = ckpt.latest_step(task.ckpt_dir)
-        if start is None:
-            params = jax.jit(lambda k: lm.init(k)[0])(
-                jax.random.key(task.rcfg.seed))
-            state = opt.init(params)
-            start = 0
-        else:
-            abs_state = opt.init_abstract(lm.init(None, abstract=True)[0])
-            state, start = ckpt.restore(task.ckpt_dir, abs_state)
-        batch_fn = synthetic_batches(task.rcfg, mesh)
-        end = min(start + self.steps_per_tick, task.num_steps)
-        for step in range(start, end):
-            if fail and step == start + 1:
-                task.restarts += 1
-                return  # simulated preemption: resume from last checkpoint
-            state, metrics = jit_step(state, batch_fn(step))
-            task.losses.append(float(metrics["loss"]))
-        ckpt.save(task.ckpt_dir, end, state)
-        task.steps_done = end
+        """Run ``steps_per_tick`` steps of a task on the devices it holds.
+        Arrays made here without a placement land on its first device; a
+        multi-device job's step spreads them over its mesh."""
+        devices = self.devices_of(task)
+        mesh = self._mesh_for(devices)
+        with jax.default_device(devices[0]):
+            lm = LM(task.rcfg.model)
+            step_fn, rt, opt = build_train_step(lm, task.rcfg, mesh)
+            jit_step = jax.jit(step_fn, donate_argnums=(0,))
+            start = ckpt.latest_step(task.ckpt_dir)
+            if start is None:
+                params = jax.jit(lambda k: lm.init(k)[0])(
+                    jax.random.key(task.rcfg.seed))
+                state = opt.init(params)
+                start = 0
+            else:
+                abs_state = opt.init_abstract(lm.init(None, abstract=True)[0])
+                state, start = ckpt.restore(task.ckpt_dir, abs_state)
+            batch_fn = synthetic_batches(task.rcfg, mesh)
+            end = min(start + self.steps_per_tick, task.num_steps)
+            for step in range(start, end):
+                if fail and step == start + 1:
+                    task.restarts += 1
+                    # simulated preemption: resume from the last checkpoint
+                    del task.losses[len(task.losses) - (step - start):]
+                    return
+                state, metrics = jit_step(state, batch_fn(step))
+                task.losses.append(float(metrics["loss"]))
+            ckpt.save(task.ckpt_dir, end, state)
+            task.steps_done = end
 
     def tick(self, *, fail_task: str | None = None) -> None:
         """One control cycle: finishes -> release -> scan/schedule -> train."""
@@ -182,7 +214,7 @@ class ElasticController:
                 grow = task.alloc
                 if self.env.free >= grow and task.alloc < 2 * task.nodes:
                     self.env.grow(task, grow)
-                    task.alloc += grow
+                    task.devices += self._take_devices(grow)
                     task.resizes += 1
         # 5) run one segment of every running job
         for task in list(self.running):
@@ -195,12 +227,14 @@ class ElasticController:
             for task in self.running:
                 if task.alloc > task.nodes:
                     self.env.shrink(task, task.alloc - task.nodes)
-                    task.alloc = task.nodes
+                    self._return_devices(task.devices[task.nodes:])
+                    del task.devices[task.nodes:]
                     task.resizes += 1
 
     def _flush_done(self, *, reschedule: bool) -> None:
         for task in self._done_last_tick:
-            task.alloc = 0
+            self._return_devices(task.devices)
+            task.devices = []
             self.finished.append(task)
             self.env.finish(task, reschedule=reschedule)
         self._done_last_tick.clear()

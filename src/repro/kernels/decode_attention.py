@@ -1,12 +1,18 @@
 """Flash-decoding as a Pallas TPU kernel (single-token GQA vs KV cache).
 
 GPU flash-decoding splits the KV cache across SMs and combines partial
-softmaxes. The TPU analogue: the grid is (batch, kv_heads, kv_blocks) with
-the kv-block axis innermost/sequential; the (G, hd) output tile for one kv
-head's query group plus its fp32 (m, l) accumulators stay resident in VMEM
-across the sweep. GQA is exploited directly — queries arrive grouped per
-kv head, so no repeated-KV materialization ever touches HBM. Length masking
-uses the per-row cache fill (continuous batching: every row differs).
+softmaxes. The TPU analogue: the grid is (batch, kv_blocks) with the
+kv-block axis innermost/sequential; the (KVH, G, hd) output tile for one
+row plus its fp32 (m, l) accumulators stay resident in VMEM across the
+sweep. GQA is exploited directly — queries arrive grouped per kv head, so
+no repeated-KV materialization ever touches HBM. Length masking uses the
+per-row cache fill (continuous batching: every row differs), which rides
+in as a scalar-prefetch operand.
+
+A K/V block holds every KV head of ``block_s`` positions, (block_s, KVH,
+hd): its last two dimensions are the array's own, which is what the TPU's
+(8, 128) tiling rule accepts for any KVH and head_dim. A per-head block
+(KVH extent 1) is refused by the compiler.
 
 Across-chip sequence sharding of the same computation lives in
 repro.parallel.collectives (shard_map + psum combine); this kernel is the
@@ -21,16 +27,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.parallel.compat import tpu_compiler_params
-
 NEG_INF = -1e30
 
 
-def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
-                   *, block_s: int, scale: float):
-    sj = pl.program_id(2)
-    ns = pl.num_programs(2)
-    length = len_ref[0]
+def decode_block(length, sj, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
+                 acc_scr, *, block_s: int, scale: float):
+    """Online-softmax step over one (block_s, KVH, hd) K/V block for every
+    KV head of one row. Shared by the contiguous and the paged kernel, so
+    the two run the same float op sequence over the same blocks."""
+    ns = pl.num_programs(1)
+    n_kv = k_ref.shape[2]
 
     @pl.when(sj == 0)
     def _init():
@@ -40,26 +46,42 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
 
     @pl.when(sj * block_s < length)
     def _block():
-        q = q_ref[0, 0].astype(jnp.float32)            # (G, hd)
-        k = k_ref[0, :, 0].astype(jnp.float32)         # (bs, hd)
-        v = v_ref[0, :, 0].astype(jnp.float32)         # (bs, hd)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        pos = sj * block_s + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(pos < length, s, NEG_INF)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1)
-        m_scr[...] = m_new
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        for h in range(n_kv):
+            q = q_ref[0, h].astype(jnp.float32)            # (G, hd)
+            k = k_ref[0, :, h].astype(jnp.float32)         # (bs, hd)
+            v = v_ref[0, :, h].astype(jnp.float32)         # (bs, hd)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32) * scale
+            pos = sj * block_s + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(pos < length, s, NEG_INF)
+            m_prev = m_scr[h]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new[:, None])
+            l_scr[h] = l_scr[h] * alpha + jnp.sum(p, axis=1)
+            m_scr[h] = m_new
+            acc_scr[h] = acc_scr[h] * alpha[:, None] + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
     @pl.when(sj == ns - 1)
     def _fin():
-        l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, 0] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+        for h in range(n_kv):
+            l = jnp.maximum(l_scr[h], 1e-30)
+            o_ref[0, h] = (acc_scr[h] / l[:, None]).astype(o_ref.dtype)
+
+
+def decode_scratch(n_kv: int, groups: int, hd: int):
+    return [pltpu.VMEM((n_kv, groups), jnp.float32),
+            pltpu.VMEM((n_kv, groups), jnp.float32),
+            pltpu.VMEM((n_kv, groups, hd), jnp.float32)]
+
+
+def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
+                   *, block_s: int, scale: float):
+    decode_block(len_ref[pl.program_id(0)], pl.program_id(1), q_ref, k_ref,
+                 v_ref, o_ref, m_scr, l_scr, acc_scr, block_s=block_s,
+                 scale=scale)
 
 
 @functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
@@ -79,28 +101,21 @@ def decode_attention(q, k_cache, v_cache, lengths, *, block_s: int = 512,
         v_cache = jnp.pad(v_cache, ((0, 0), (0, pad_s), (0, 0), (0, 0)))
     Sp = S + pad_s
     qg = q.reshape(B, KVH, G, hd)
-    grid = (B, KVH, Sp // block_s)
+    kv_spec = pl.BlockSpec((1, block_s, KVH, hd), lambda b, j, ln: (b, j, 0, 0))
+    row_spec = pl.BlockSpec((1, KVH, G, hd), lambda b, j, ln: (b, 0, 0, 0))
     out = pl.pallas_call(
         functools.partial(_decode_kernel, block_s=block_s,
                           scale=1.0 / (hd ** 0.5)),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1,), lambda b, h, j: (b,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, G, hd), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((1, block_s, 1, hd), lambda b, h, j: (b, j, h, 0)),
-            pl.BlockSpec((1, block_s, 1, hd), lambda b, h, j: (b, j, h, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, hd), lambda b, h, j: (b, h, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, Sp // block_s),
+            in_specs=[row_spec, kv_spec, kv_spec],
+            out_specs=row_spec,
+            scratch_shapes=decode_scratch(KVH, G, hd),
+        ),
         out_shape=jax.ShapeDtypeStruct((B, KVH, G, hd), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G, hd), jnp.float32),
-        ],
-        compiler_params=tpu_compiler_params(
-            pltpu,
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(lengths.astype(jnp.int32), qg, k_cache, v_cache)
     return out.reshape(B, H, hd)

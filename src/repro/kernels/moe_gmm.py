@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.parallel.compat import tpu_compiler_params
-
 
 def _gmm_kernel(x_ref, w_ref, o_ref, acc_scr):
     dk = pl.program_id(3)
@@ -66,8 +64,7 @@ def moe_gmm(x, w, *, block_c: int = 128, block_f: int = 128,
                                lambda e, i, j, k: (e, i, j)),
         out_shape=jax.ShapeDtypeStruct((E, C, f), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_c, block_f), jnp.float32)],
-        compiler_params=tpu_compiler_params(
-            pltpu,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -5,16 +5,16 @@ is a shared pool of fixed-size pages — (n_pages, page_size, KVH, hd) — and
 each batch row reads its blocks *through* a per-row page table instead of a
 contiguous (B, S, KVH, hd) slab. The page table and row lengths ride in as
 scalar-prefetch operands (PrefetchScalarGridSpec), so the block index map
-itself performs the gather: grid step (b, h, j) fetches physical page
-``page_table[b, j]``. No gathered copy of the cache ever materializes in
-HBM — the DMA engine walks the table.
+itself performs the gather: grid step (b, j) fetches physical page
+``page_table[b, j]``, all KV heads at once. No gathered copy of the cache
+ever materializes in HBM — the DMA engine walks the table.
 
-With ``page_size`` equal to the contiguous kernel's ``block_s`` the float
-op sequence is identical, so outputs are bit-identical to
-``decode_attention`` over the equivalent contiguous cache (pinned in
-tests/test_paged.py, interpret mode). Rows with ``length == 0`` skip every
-block and emit exact zeros — the same zero-fill contract kernels/ref.py
-defines.
+The block body is ``decode_attention.decode_block`` itself, so with
+``page_size`` equal to the contiguous kernel's ``block_s`` outputs are
+bit-identical to ``decode_attention`` over the equivalent contiguous cache
+(pinned in tests/test_paged.py, interpret mode). Rows with ``length == 0``
+skip every block and emit exact zeros — the same zero-fill contract
+kernels/ref.py defines.
 """
 from __future__ import annotations
 
@@ -25,48 +25,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.parallel.compat import tpu_compiler_params
-
-NEG_INF = -1e30
+from repro.kernels.decode_attention import decode_block, decode_scratch
 
 
 def _paged_decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
                          m_scr, l_scr, acc_scr, *, page_size: int,
                          scale: float):
     del pt_ref  # consumed by the index maps; the body only needs lengths
-    b = pl.program_id(0)
-    sj = pl.program_id(2)
-    ns = pl.num_programs(2)
-    length = len_ref[b]
-
-    @pl.when(sj == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    @pl.when(sj * page_size < length)
-    def _block():
-        q = q_ref[0, 0].astype(jnp.float32)            # (G, hd)
-        k = k_ref[0, :, 0].astype(jnp.float32)         # (ps, hd)
-        v = v_ref[0, :, 0].astype(jnp.float32)         # (ps, hd)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        pos = sj * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(pos < length, s, NEG_INF)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1)
-        m_scr[...] = m_new
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-
-    @pl.when(sj == ns - 1)
-    def _fin():
-        l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, 0] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+    decode_block(len_ref[pl.program_id(0)], pl.program_id(1), q_ref, k_ref,
+                 v_ref, o_ref, m_scr, l_scr, acc_scr, block_s=page_size,
+                 scale=scale)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -86,33 +54,23 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
     G = H // KVH
     n_pt = page_table.shape[1]
     qg = q.reshape(B, KVH, G, hd)
-    grid = (B, KVH, n_pt)
+    kv_spec = pl.BlockSpec((1, page_size, KVH, hd),
+                           lambda b, j, pt, ln: (pt[b, j], 0, 0, 0))
+    row_spec = pl.BlockSpec((1, KVH, G, hd),
+                            lambda b, j, pt, ln: (b, 0, 0, 0))
     out = pl.pallas_call(
         functools.partial(_paged_decode_kernel, page_size=page_size,
                           scale=1.0 / (hd ** 0.5)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, 1, G, hd),
-                             lambda b, h, j, pt, ln: (b, h, 0, 0)),
-                pl.BlockSpec((1, page_size, 1, hd),
-                             lambda b, h, j, pt, ln: (pt[b, j], 0, h, 0)),
-                pl.BlockSpec((1, page_size, 1, hd),
-                             lambda b, h, j, pt, ln: (pt[b, j], 0, h, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, 1, G, hd),
-                                   lambda b, h, j, pt, ln: (b, h, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((G,), jnp.float32),
-                pltpu.VMEM((G,), jnp.float32),
-                pltpu.VMEM((G, hd), jnp.float32),
-            ],
+            grid=(B, n_pt),
+            in_specs=[row_spec, kv_spec, kv_spec],
+            out_specs=row_spec,
+            scratch_shapes=decode_scratch(KVH, G, hd),
         ),
         out_shape=jax.ShapeDtypeStruct((B, KVH, G, hd), q.dtype),
-        compiler_params=tpu_compiler_params(
-            pltpu,
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
       qg, k_pages, v_pages)

@@ -14,6 +14,7 @@ import numpy as np
 
 from repro.configs import ARCHS, get_smoke_config
 from repro.configs.base import ParallelConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.lm import LM
 from repro.serve.engine import Engine, Request
 
@@ -27,11 +28,12 @@ def main():
     ap.add_argument("--max-batch", type=int, default=4)
     ap.add_argument("--max-len", type=int, default=64)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch)
     lm = LM(cfg)
     rt = lm.runtime(ParallelConfig(attn_q_chunk=16, attn_kv_chunk=16))
-    params = lm.init(jax.random.key(0))[0]
+    params = jax.jit(lambda k: lm.init(k)[0])(jax.random.key(0))
     engine = Engine(lm, params, rt, max_batch=args.max_batch,
                     max_len=args.max_len)
     rng = np.random.default_rng(0)

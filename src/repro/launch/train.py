@@ -13,6 +13,7 @@ import jax
 
 from repro.configs import ARCHS, SHAPES, get_config, get_smoke_config
 from repro.configs.base import ParallelConfig, RunConfig, ShapeConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.train.loop import train_loop
 
@@ -30,6 +31,7 @@ def main():
                     help="data-axis size (0 = all local devices)")
     ap.add_argument("--model-axis", type=int, default=1)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.smoke:
         cfg = get_smoke_config(args.arch)

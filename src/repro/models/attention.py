@@ -68,10 +68,16 @@ def repeat_kv(k, n_heads: int):
 
 
 def _block_attn(qb, kb, vb, mask, scale):
-    """One (Bq x Bk) block: returns (o_acc, m, l) in fp32."""
+    """One (Bq x Bk) block: returns (o_acc, m, l) in fp32.
+
+    The running max ``m`` only shifts the exponent and cancels in o / l, so
+    it carries no gradient. Differentiating ``max`` would divide by the
+    count of entries equal to it; under remat on the TPU the recomputed
+    scores may keep excess precision in one fusion and not in another, so
+    no entry equals the max, the count is 0, and dQ/dK become NaN."""
     s = jnp.einsum("bqhd,bkhd->bhqk", qb, kb).astype(jnp.float32) * scale
     s = jnp.where(mask, s, NEG_INF)
-    m = jnp.max(s, axis=-1)                      # (B,H,Q)
+    m = jax.lax.stop_gradient(jnp.max(s, axis=-1))  # (B,H,Q)
     p = jnp.exp(s - m[..., None])
     l = jnp.sum(p, axis=-1)                      # (B,H,Q)
     o = jnp.einsum("bhqk,bkhd->bhqd", p.astype(vb.dtype), vb).astype(jnp.float32)

@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.parallel.sharding import AXIS_MODEL, batch_axes
-from repro.parallel.compat import shard_map
 
 
 def moe_init(scope, cfg):
@@ -106,7 +105,7 @@ def moe_apply(p, cfg, x, ids, wts, mesh=None):
         btotal *= mesh.shape[a]
     # replicate batch when it cannot shard (e.g. long-context decode B=1)
     bspec = P(bax if (bax and x.shape[0] % btotal == 0) else None)
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda xx, ii, ww, wi, wg, wo: _moe_local(
             xx, ii, ww, wi, wg, wo, cfg=cfg, n_local=n_local, axis=AXIS_MODEL),
         mesh=mesh,

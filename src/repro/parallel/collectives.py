@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.parallel.sharding import AXIS_MODEL, batch_axes
-from repro.parallel.compat import axis_size, shard_map
 
 NEG_INF = -1e30
 
@@ -32,7 +31,7 @@ def _ring_body(q, k, v, *, axis: str, causal: bool):
     """Per-shard body. q: (B, S_loc, H, hd); k/v: (B, S_loc, KVH, hd) —
     the ring rotates the *unrepeated* GQA kv shards (kv_dim bytes per hop,
     not H x hd: 8x less wire for the kv=8 archs)."""
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     idx = jax.lax.axis_index(axis)
     B, Sl, H, hd = q.shape
     KVH = k.shape[2]
@@ -83,7 +82,7 @@ def ring_attention(q, k, v, mesh, axis=AXIS_MODEL, *, causal=True):
         btotal *= mesh.shape[a]
     b = bax if (bax and q.shape[0] % btotal == 0) else None
     spec = P(b, axis, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda qq, kk, vv: _ring_body(qq, kk, vv, axis=axis, causal=causal),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False)
@@ -148,7 +147,7 @@ def seq_sharded_decode_attention(q, k_cache, v_cache, lengths, new_k, new_v,
         btotal *= mesh.shape[a]
     # replicate the batch dim when it cannot shard (e.g. long-context B=1)
     b = bax if (bax and q.shape[0] % btotal == 0) else None
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda qq, kk, vv, ll, nk, nv: _partial_decode(
             qq, kk, vv, ll, nk, nv, axis, k_cache.shape[1]),
         mesh=mesh,

@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.parallel.sharding import AXIS_POD
-from repro.parallel.compat import shard_map
 
 
 def _quantize(x):
@@ -63,7 +62,7 @@ def build_pod_compressed_grad_fn(grad_fn, mesh):
             metrics = jax.tree.map(lambda m: jax.lax.pmean(m, AXIS_POD), metrics)
             return (loss, metrics), grads
 
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(), P(AXIS_POD)),   # prefix specs: pod placement only
             out_specs=P(),

@@ -2,17 +2,18 @@
 grad fn on a multi-'pod' host mesh (subprocess sets the device count)."""
 from __future__ import annotations
 
+import pathlib
 import subprocess
 import sys
 
-import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from tests.conftest import given, settings, st
 
 from repro.parallel.compression import _quantize
+
+REPO_ROOT = pathlib.Path(__file__).resolve().parents[1]
 
 
 @given(st.lists(st.floats(-1e3, 1e3, allow_nan=False, width=32),
@@ -67,12 +68,6 @@ print("OK")
 """
 
 
-@pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="partial-manual shard_map (axis_names subset of the mesh) "
-           "needs the top-level jax.shard_map API; on older jax the "
-           "experimental fallback's auto= path aborts inside XLA's SPMD "
-           "partitioner (IsManualSubgroup check) for this program")
 def test_pod_compressed_grads_match_reference():
     r = subprocess.run([sys.executable, "-c", _SUBPROC],
                        capture_output=True, text=True,
@@ -82,6 +77,6 @@ def test_pod_compressed_grads_match_reference():
                             # without the pin jax probes for TPU metadata
                             # for minutes before falling back
                             "JAX_PLATFORMS": "cpu"},
-                       cwd="/root/repo", timeout=900)
+                       cwd=REPO_ROOT, timeout=900)
     assert r.returncode == 0, r.stderr[-2000:]
     assert "OK" in r.stdout

@@ -66,10 +66,11 @@ def test_mesh_wider_than_device_pool_raises():
 
     class _Stub:
         devices = [object(), object()]
+        _free_devices = [0, 1]
 
     # unbound call on a stub: the guard must fire before any jax import
     with pytest.raises(RuntimeError, match="mesh wider than device pool"):
-        ElasticController._mesh_for(_Stub(), 3)
+        ElasticController._take_devices(_Stub(), 3)
 
 
 # ------------------------------------------------------- sim/engine.py

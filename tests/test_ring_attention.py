@@ -1,6 +1,7 @@
 """Ring attention (sequence-parallel prefill) vs the attention oracle."""
 from __future__ import annotations
 
+import pathlib
 import subprocess
 import sys
 
@@ -10,6 +11,8 @@ import numpy as np
 
 from repro.parallel.collectives import ring_attention
 from repro.kernels.ref import flash_attention_ref
+
+REPO_ROOT = pathlib.Path(__file__).resolve().parents[1]
 
 
 def test_single_device_fallback_matches_oracle():
@@ -66,6 +69,6 @@ def test_ring_matches_oracle_on_sharded_mesh():
                             # without the pin jax probes for TPU metadata
                             # for minutes before falling back
                             "JAX_PLATFORMS": "cpu"},
-                       cwd="/root/repo", timeout=900)
+                       cwd=REPO_ROOT, timeout=900)
     assert r.returncode == 0, r.stderr[-2000:]
     assert "OK" in r.stdout

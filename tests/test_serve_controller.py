@@ -100,3 +100,51 @@ def test_controller_policy_grows_for_queue(tmp_path):
     assert ctl.owned >= 2   # grew beyond the single initial node
     ctl.run()
     assert len(ctl.finished) == 3
+
+
+class _PlacementController(ElasticController):
+    """Segments record the devices they are given instead of training."""
+
+    def __init__(self, **kw):
+        super().__init__(**kw)
+        self.placements: dict[int, dict[str, tuple[list, list]]] = {}
+
+    def _run_segment(self, task, fail=False):
+        self.placements.setdefault(self._tick, {})[task.name] = (
+            list(task.devices), self.devices_of(task))
+        task.steps_done = min(task.steps_done + self.steps_per_tick,
+                              task.num_steps)
+
+
+def test_concurrent_jobs_hold_disjoint_devices():
+    """Jobs running at the same tick never share a device index, through
+    grows and shrinks; each segment gets the devices its indices name,
+    and a one-node job gets its own device, not the first (default) one.
+    Indices, not device objects, are the contract: the CPU tests pad the
+    list with copies of one device."""
+    devices = [object() for _ in range(4)]
+    ctl = _PlacementController(policy=MgmtPolicy.htc(3, 1.0),
+                               provision=ProvisionService(capacity=4),
+                               devices=devices, steps_per_tick=1,
+                               elastic_grow=True)
+    a = TrainTask("a", None, nodes=1, num_steps=3, ckpt_dir="")
+    b = TrainTask("b", None, nodes=1, num_steps=6, ckpt_dir="")
+    c = TrainTask("c", None, nodes=2, num_steps=2, ckpt_dir="")
+    ctl.submit(a)
+    ctl.submit(b)
+    ctl.tick()
+    ctl.submit(c)            # queued demand: grown jobs shrink back
+    ctl.run()
+    ctl.destroy()
+    assert len(ctl.finished) == 3
+    for tick, held in ctl.placements.items():
+        idx = [i for ids, _ in held.values() for i in ids]
+        assert len(idx) == len(set(idx)), (tick, held)
+        for ids, objs in held.values():
+            assert objs == [devices[i] for i in ids]
+    assert ctl.placements[1] == {"a": ([0, 2], [devices[0], devices[2]]),
+                                 "b": ([1], [devices[1]])}
+    assert a.resizes >= 2                     # grown, then shrunk
+    assert ctl.placements[6]["b"][0] == [1, 0]   # grew into a's device
+    assert ctl._free_devices == [0, 1, 2, 3]
+    assert all(t.devices == [] and t.alloc == 0 for t in ctl.finished)
