@@ -97,8 +97,6 @@ class Engine:
                 donate_argnums=(3,))
         self._prefill = {}
         self.steps = 0
-        #: (max_batch, vocab) logits of the latest decode step, on device
-        self.last_logits = None
         # ---- vectorized slot accounting ----
         ncb = lm.cfg.n_codebooks
         tok_shape = (max_batch,) if ncb <= 1 else (max_batch, ncb)
@@ -283,7 +281,6 @@ class Engine:
             logits, self.caches = self._decode(
                 self.params, jnp.asarray(toks), self.lengths, self.caches,
                 jnp.asarray(self._page_table))
-        self.last_logits = logits
         nxt = np.asarray(jnp.argmax(logits, axis=-1))  # (B,) or (B,ncb)
         mask = self._active_mask
         self._last_tok[mask] = nxt[mask]
