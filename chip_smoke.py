@@ -109,41 +109,20 @@ def check(cond: bool, msg: str) -> None:
         raise RuntimeError(f"chip_smoke: {msg}")
 
 
-class CompileClock:
-    """Sums XLA compile durations (a load from the persistent cache counts
-    as one) and counts persistent-cache hits, so each phase's wall time
-    splits into compile and the rest (tracing, host work, device work)."""
-
-    EVENT = "/jax/core/compile/backend_compile_duration"
-
-    def __init__(self):
-        import jax
-        self.seconds = 0.0
-        self.compiles = 0
-        self.cache_hits = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event: str, secs: float, **_) -> None:
-        if event == self.EVENT:
-            self.seconds += secs
-            self.compiles += 1
-
-    def _event(self, event: str, **_) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-
-
 class Phase:
-    """Times one phase and prints its wall, compile and run seconds."""
+    """Times one phase and prints its wall, compile and run seconds, from
+    the program's compile counter (a load from the persistent cache counts
+    as a program)."""
 
-    def __init__(self, name: str, clock: CompileClock, device: str):
-        self.name, self.clock, self.device = name, clock, device
+    def __init__(self, name: str, device: str):
+        from repro import obs
+        self.name, self.device = name, device
+        self.clock = obs.compile_counter()
 
     def __enter__(self):
         self.t0 = time.perf_counter()
         self.c0 = self.clock.seconds
-        self.n0 = self.clock.compiles
+        self.n0 = self.clock.programs
         self.h0 = self.clock.cache_hits
         log(f"[{self.name}] start")
         return self
@@ -154,7 +133,7 @@ class Phase:
         wall = time.perf_counter() - self.t0
         comp = self.clock.seconds - self.c0
         log(f"[{self.name}] {self.device}: wall {wall:.2f}s = XLA compile "
-            f"{comp:.2f}s ({self.clock.compiles - self.n0} programs, "
+            f"{comp:.2f}s ({self.clock.programs - self.n0} programs, "
             f"{self.clock.cache_hits - self.h0} from the persistent cache) "
             f"+ the rest {wall - comp:.2f}s")
         return False
@@ -305,7 +284,7 @@ def fleet_twins(engine, *, workflows: int, seed: int = SEED):
     return real_stats, twin.run().as_dict(), real
 
 
-def serve_phases(lm, rt, params, clock: CompileClock, device: str, *,
+def serve_phases(lm, rt, params, device: str, *,
                  max_batch=MAX_BATCH, max_len=MAX_LEN, page_size=PAGE_SIZE,
                  n_requests=N_REQUESTS, prompt_lens=PROMPT_LENS,
                  new_tokens=NEW_TOKENS, workflows=FLEET_WORKFLOWS) -> None:
@@ -313,7 +292,7 @@ def serve_phases(lm, rt, params, clock: CompileClock, device: str, *,
 
     reqs = make_requests(lm.cfg, n_requests, SEED, prompt_lens, new_tokens)
     probe = reqs[:max_batch]
-    with Phase("A contiguous engine", clock, device):
+    with Phase("A contiguous engine", device):
         eng = keep_logits(Engine(lm, params, rt, max_batch=max_batch,
                                  max_len=max_len, prefill_chunk=PREFILL_CHUNK))
         toks_a = serve_all(eng, fresh(reqs))
@@ -322,7 +301,7 @@ def serve_phases(lm, rt, params, clock: CompileClock, device: str, *,
             f"{eng.steps} decode steps, all admitted and complete")
         logits_a = probe_logits(eng, probe, PROBE_STEPS)
 
-    with Phase("C cache consistency", clock, device):
+    with Phase("C cache consistency", device):
         plen = prompt_lens[len(prompt_lens) // 2]
         n_new = new_tokens[0]
         pair = fresh([r for r in reqs if len(r.tokens) == plen][:2], n_new)
@@ -344,7 +323,7 @@ def serve_phases(lm, rt, params, clock: CompileClock, device: str, *,
         log(f"[C] 2 requests x steps {at}: max |engine - float32 ref| = "
             f"{worst:.4g} of the logits' scale (bound {REFERENCE_TOL})")
 
-    with Phase("B paged engine", clock, device):
+    with Phase("B paged engine", device):
         eng = keep_logits(Engine(lm, params, rt, max_batch=max_batch,
                                  max_len=max_len, prefill_chunk=PREFILL_CHUNK,
                                  page_size=page_size))
@@ -365,7 +344,7 @@ def serve_phases(lm, rt, params, clock: CompileClock, device: str, *,
             f"batch: max |paged - contiguous| = {worst:.4g} of the "
             f"logits' scale (bound {PAGED_TOL})")
 
-    with Phase("D DSP fleet", clock, device):
+    with Phase("D DSP fleet", device):
         real, emu, fleet = fleet_twins(eng, workflows=workflows)
         check(real["workflows_completed"] == real["workflows_expected"],
               f"fleet completed {real['workflows_completed']}/"
@@ -462,7 +441,7 @@ def planted(fault: str):
     return make
 
 
-def train_phase(dev, rcfg, clock: CompileClock, label: str) -> None:
+def train_phase(dev, rcfg, label: str) -> None:
     """E on one chip: train-0 preempted once must repeat its unpreempted
     losses exactly (same program, same state, same chip), and each planted
     fault must move them by more than ``LOSS_RTOL``, the bound the
@@ -470,7 +449,7 @@ def train_phase(dev, rcfg, clock: CompileClock, label: str) -> None:
     from unittest import mock
     name = "train-0"
     job = {name: JOB_STEPS[name]}
-    with Phase("E one-chip training", clock, label):
+    with Phase("E one-chip training", label):
         alone, = run_jobs([dev], rcfg, job, initial=1, per_tick=job[name])
         preempted, = run_jobs([dev], rcfg, job, initial=1,
                               per_tick=STEPS_PER_TICK, fail_at={2: name})
@@ -493,18 +472,18 @@ def train_phase(dev, rcfg, clock: CompileClock, label: str) -> None:
               f"a planted fault stays within {LOSS_RTOL}: {gaps}")
 
 
-def elastic_phase(devices, rcfg, clock: CompileClock, label: str) -> None:
+def elastic_phase(devices, rcfg, label: str) -> None:
     """Two jobs on four devices: train-0 grows onto a second device before
     it runs, train-1 is preempted once and then grows onto the device
     train-0 returns. Each job's losses are compared with the same job run
     alone on one device."""
-    with Phase("E elastic training", clock, label):
+    with Phase("E elastic training", label):
         elastic = run_jobs(devices, rcfg, JOB_STEPS, initial=3, grow=True,
                            per_tick=STEPS_PER_TICK, fail_at={2: "train-1"})
         check(all(t.resizes for t in elastic),
               "a job was never resized onto more devices")
         check(elastic[1].restarts == 1, "the preemption was not absorbed")
-    with Phase("E one-chip references", clock, label):
+    with Phase("E one-chip references", label):
         alone = run_jobs(devices, rcfg, JOB_STEPS, initial=2,
                          per_tick=max(JOB_STEPS.values()))
     for got, ref in zip(elastic, alone):
@@ -518,20 +497,20 @@ def elastic_phase(devices, rcfg, clock: CompileClock, label: str) -> None:
 
 
 # ------------------------------------------------------------------ main
-def serve(clock: CompileClock, label: str) -> None:
+def serve(label: str) -> None:
     """Phases A-D; the 20-layer weights are freed when this returns."""
     import jax
     from repro.models.lm import LM
     cfg = granite(SERVE_LAYERS)
     lm = LM(cfg)
     rt = lm.runtime()
-    with Phase("init", clock, label):
+    with Phase("init", label):
         params = jax.jit(lambda k: lm.init(k)[0])(jax.random.key(SEED))
         jax.block_until_ready(params)
     n = sum(x.size for x in jax.tree.leaves(params))
     log(f"granite-3-8b, {cfg.n_layers} of 40 layers: {n / 1e9:.3f} B "
         f"parameters on {label}")
-    serve_phases(lm, rt, params, clock, label)
+    serve_phases(lm, rt, params, label)
 
 
 def main(argv=None) -> int:
@@ -561,15 +540,14 @@ def main(argv=None) -> int:
     log(f"jax {jax.__version__}, jaxlib {jaxlib.__version__}, libtpu "
         f"{libtpu}; {len(devices)} x {kind}; compile cache "
         f"{enable_compile_cache()}")
-    clock = CompileClock()
 
     rcfg = train_config(granite(TRAIN_LAYERS))
     if args.chips == 4:
-        elastic_phase(devices[:4], rcfg, clock, device)
+        elastic_phase(devices[:4], rcfg, device)
     else:
-        train_phase(devices[0], rcfg, clock, device)
+        train_phase(devices[0], rcfg, device)
         gc.collect()
-        serve(clock, device)
+        serve(device)
     for i, d in enumerate(devices[:args.chips]):
         stats = d.memory_stats() or {}
         if "peak_bytes_in_use" in stats:

@@ -13,6 +13,18 @@ per request. Slot accounting (last-token gather, output accumulation,
 length bumps, finish detection) is vectorized over NumPy slot arrays; the
 only per-request Python is materializing finished requests.
 
+The jitted programs are named ``engine_decode`` and ``engine_prefill``
+(``jit_engine_decode`` and ``jit_engine_prefill`` in a profiler trace and
+in ``repro.obs.compile_counter()``). Admission and the decode step are
+cut into ``repro.obs`` spans, off unless ``obs.enable(True)`` was called:
+``engine.admit`` holds one ``engine.prefill`` (batch, prefill program,
+first tokens to the host) and one ``engine.splice`` (per-row slices and
+cache splices) per prefill group; ``engine.step`` holds
+``engine.decode`` (inputs and the decode dispatch), ``engine.tokens``
+(the argmax and the tokens to the host, where the host waits for the
+chip) and ``engine.account`` (slot accounting). ``Engine.counters``
+counts prefill rows, padding included, and the padding rows.
+
 MTC workflows (Montage-style DAGs of inference tasks) are driven by
 ``repro.core.tre.MTCRuntimeEnv``, which feeds this engine only tasks whose
 dependencies completed — the DawningCloud "trigger monitor" role. The env
@@ -29,6 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.models.lm import LM, Runtime
 
 
@@ -66,9 +79,10 @@ class Engine:
         if page_size is None:
             self.pager = None
             self.caches = lm.init_cache(max_batch, max_len)
-            self._decode = jax.jit(
-                lambda p, t, l, c: lm.decode(p, rt, t, l, c),
-                donate_argnums=(3,))
+
+            def engine_decode(p, t, l, c):
+                return lm.decode(p, rt, t, l, c)
+            self._decode = jax.jit(engine_decode, donate_argnums=(3,))
         else:
             # physical paged KV: attention caches live in one shared page
             # pool; a slot's cache is the pages its table row maps. Page 0
@@ -91,12 +105,15 @@ class Engine:
             self.caches = lm.init_paged_cache(max_batch, n_pages, page_size)
             self._page_table = np.zeros((max_batch, self.pages_per_slot),
                                         np.int32)
-            self._decode = jax.jit(
-                lambda p, t, l, c, pt: lm.decode(p, rt, t, l, c,
-                                                 page_table=pt),
-                donate_argnums=(3,))
+
+            def engine_decode(p, t, l, c, pt):
+                return lm.decode(p, rt, t, l, c, page_table=pt)
+            self._decode = jax.jit(engine_decode, donate_argnums=(3,))
         self._prefill = {}
         self.steps = 0
+        # rows run through prefill, and of them the padding rows that
+        # repeat a group's last request to fill ``prefill_chunk``
+        self.counters = {"prefill_rows": 0, "prefill_rows_padded": 0}
         # ---- vectorized slot accounting ----
         ncb = lm.cfg.n_codebooks
         tok_shape = (max_batch,) if ncb <= 1 else (max_batch, ncb)
@@ -120,9 +137,9 @@ class Engine:
     def _prefill_fn(self, plen: int, has_patches: bool):
         key = (plen, has_patches)
         if key not in self._prefill:
-            def f(params, batch):
+            def engine_prefill(params, batch):
                 return self.lm.prefill(params, self.rt, batch)
-            self._prefill[key] = jax.jit(f)
+            self._prefill[key] = jax.jit(engine_prefill)
         return self._prefill[key]
 
     def _splice_caches(self, slot: int, pre_caches):
@@ -194,37 +211,39 @@ class Engine:
         to a small discrete set; with it, groups run in fixed-size
         (padded) chunks, bounding specialization to one per prompt shape.
         """
-        groups: dict[tuple[int, bool], list[tuple[int, Request]]] = {}
-        admitted: list[Request] = []
-        order: dict[int, int] = {}          # slot -> call-order seq
-        for req in reqs:
-            if not self.free:
-                break
-            plen = len(req.tokens)
-            n_img = self.lm.cfg.n_patches if req.patches is not None else 0
-            if plen + n_img + req.max_new_tokens > self.max_len:
-                req.rejected = True
-                req.done = True
-                continue
-            slot = self.free.pop()
-            if self.pager is not None:
-                need = -(-(plen + n_img + req.max_new_tokens)
-                         // self.page_size)
-                pages = self.pager.alloc(slot, need)
-                self._page_table[slot] = 0
-                self._page_table[slot, :len(pages)] = pages
-            order[slot] = self._seq
-            self._seq += 1
-            groups.setdefault((len(req.tokens), req.patches is not None),
-                              []).append((slot, req))
-            admitted.append(req)
-        step = self.prefill_chunk
-        for (plen, has_patches), members in groups.items():
-            for i0 in range(0, len(members), step or len(members)):
-                part = members[i0:i0 + step] if step else members
-                self._prefill_group(plen, has_patches, part, order,
-                                    pad_to=step)
-        return admitted
+        with obs.span("engine.admit"):
+            groups: dict[tuple[int, bool], list[tuple[int, Request]]] = {}
+            admitted: list[Request] = []
+            order: dict[int, int] = {}          # slot -> call-order seq
+            for req in reqs:
+                if not self.free:
+                    break
+                plen = len(req.tokens)
+                n_img = (self.lm.cfg.n_patches if req.patches is not None
+                         else 0)
+                if plen + n_img + req.max_new_tokens > self.max_len:
+                    req.rejected = True
+                    req.done = True
+                    continue
+                slot = self.free.pop()
+                if self.pager is not None:
+                    need = -(-(plen + n_img + req.max_new_tokens)
+                             // self.page_size)
+                    pages = self.pager.alloc(slot, need)
+                    self._page_table[slot] = 0
+                    self._page_table[slot, :len(pages)] = pages
+                order[slot] = self._seq
+                self._seq += 1
+                groups.setdefault((plen, req.patches is not None),
+                                  []).append((slot, req))
+                admitted.append(req)
+            step = self.prefill_chunk
+            for (plen, has_patches), members in groups.items():
+                for i0 in range(0, len(members), step or len(members)):
+                    part = members[i0:i0 + step] if step else members
+                    self._prefill_group(plen, has_patches, part, order,
+                                        pad_to=step)
+            return admitted
 
     def _prefill_group(self, plen: int, has_patches: bool, members,
                        order: dict[int, int],
@@ -234,25 +253,31 @@ class Engine:
         (repeating the last row; padded outputs are discarded) so the
         compiled prefill is reused across admit windows of any size."""
         k = len(members)
-        rows = [np.asarray(r.tokens) for _, r in members]
-        if pad_to and k < pad_to:
-            rows.extend([rows[-1]] * (pad_to - k))
-        batch = {"tokens": jnp.asarray(np.stack(rows))}
-        if has_patches:
-            prows = [np.asarray(r.patches) for _, r in members]
-            if pad_to and k < pad_to:
-                prows.extend([prows[-1]] * (pad_to - k))
-            batch["patches"] = jnp.asarray(np.stack(prows))
         n_img = self.lm.cfg.n_patches if has_patches else 0
-        logits, pre_caches, _ = self._prefill_fn(plen, has_patches)(
-            self.params, batch)
-        toks = np.asarray(jnp.argmax(logits, axis=-1))[:k]  # (k,) or (k,ncb)
+        with obs.span("engine.prefill"):
+            rows = [np.asarray(r.tokens) for _, r in members]
+            if pad_to and k < pad_to:
+                rows.extend([rows[-1]] * (pad_to - k))
+            batch = {"tokens": jnp.asarray(np.stack(rows))}
+            if has_patches:
+                prows = [np.asarray(r.patches) for _, r in members]
+                if pad_to and k < pad_to:
+                    prows.extend([prows[-1]] * (pad_to - k))
+                batch["patches"] = jnp.asarray(np.stack(prows))
+            logits, pre_caches, _ = self._prefill_fn(plen, has_patches)(
+                self.params, batch)
+            # (k,) or (k, ncb): the first tokens, on the host
+            toks = np.asarray(jnp.argmax(logits, axis=-1))[:k]
+        self.counters["prefill_rows"] += len(rows)
+        self.counters["prefill_rows_padded"] += len(rows) - k
+        with obs.span("engine.splice"):
+            for i, (slot, _) in enumerate(members):
+                self._splice_caches(slot, jax.tree.map(
+                    lambda a, _i=i: jax.lax.dynamic_slice_in_dim(
+                        a, _i, 1, axis=1),
+                    pre_caches))
         slots = np.array([s for s, _ in members])
         for i, (slot, req) in enumerate(members):
-            self._splice_caches(slot, jax.tree.map(
-                lambda a, _i=i: jax.lax.dynamic_slice_in_dim(a, _i, 1,
-                                                             axis=1),
-                pre_caches))
             self.active[slot] = req
             req.out_tokens.append(toks[i])
         self.lengths = self.lengths.at[slots].set(plen + n_img)
@@ -271,6 +296,18 @@ class Engine:
         """One decode step for all active slots; returns finished requests."""
         if not self.active:
             return []
+        with obs.span("engine.step"):
+            with obs.span("engine.decode"):
+                logits = self._dispatch_decode()
+            with obs.span("engine.tokens"):
+                # (B,) or (B, ncb), on the host: waits for the step
+                nxt = np.asarray(jnp.argmax(logits, axis=-1))
+            with obs.span("engine.account"):
+                return self._account(nxt)
+
+    def _dispatch_decode(self):
+        """The decode program on every slot's last token; returns its
+        logits, still on the device, and keeps the updated caches."""
         ncb = self.lm.cfg.n_codebooks
         toks = (self._last_tok[:, None] if ncb <= 1
                 else self._last_tok[:, None, :])
@@ -281,7 +318,11 @@ class Engine:
             logits, self.caches = self._decode(
                 self.params, jnp.asarray(toks), self.lengths, self.caches,
                 jnp.asarray(self._page_table))
-        nxt = np.asarray(jnp.argmax(logits, axis=-1))  # (B,) or (B,ncb)
+        return logits
+
+    def _account(self, nxt: np.ndarray) -> list[Request]:
+        """Append the step's tokens ``nxt`` to the active slots, advance
+        their lengths, and free and return the requests that finished."""
         mask = self._active_mask
         self._last_tok[mask] = nxt[mask]
         self._out_buf[mask, self._out_len[mask]] = nxt[mask]

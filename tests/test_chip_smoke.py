@@ -27,20 +27,19 @@ from repro.configs.base import smoke_reduce
 from repro.models.lm import LM
 
 phase = sys.argv[1]
-clock = cs.CompileClock()
 label = "cpu"
 if phase == "serve":
     lm = LM(smoke_reduce(cs.granite(2)))
     params = jax.jit(lambda k: lm.init(k)[0])(jax.random.key(cs.SEED))
-    cs.serve_phases(lm, lm.runtime(), params, clock, label, max_batch=8,
-                    max_len=64, page_size=8, n_requests=6,
-                    prompt_lens=(8, 16, 24), new_tokens=(8, 16), workflows=1)
+    cs.serve_phases(lm, lm.runtime(), params, label, max_batch=8, max_len=64,
+                    page_size=8, n_requests=6, prompt_lens=(8, 16, 24),
+                    new_tokens=(8, 16), workflows=1)
 else:
     rcfg = cs.train_config(smoke_reduce(cs.granite(2)), seq_len=32, batch=8)
     if phase == "train":
-        cs.train_phase(jax.devices()[0], rcfg, clock, label)
+        cs.train_phase(jax.devices()[0], rcfg, label)
     else:
-        cs.elastic_phase(jax.devices(), rcfg, clock, label)
+        cs.elastic_phase(jax.devices(), rcfg, label)
 print("PHASE OK")
 """
 
