@@ -37,14 +37,19 @@ def block_init(scope, cfg, i: int):
 
 
 def attn_block(p, cfg, rt, x, positions, cache=None, lengths=None,
-               decode=False, page_table=None):
+               decode=False, page_table=None, layer=None):
     """Returns (out (B,S,d), new_cache (k,v)).
 
-    With ``page_table`` (B, pages_per_row) the cache leaves are a shared
-    page pool (n_pages, page_size, KVH, hd): the new token's K/V scatter
-    through the table and attention runs over the gathered per-row view.
-    Gathered masked positions contribute exactly 0 probability, so the
-    result is bit-identical to the contiguous path over the same tokens.
+    Decode takes the whole stacked cache, leaves (R, B, S, KVH, hd), and
+    ``layer``, this block's index into R. It writes the new token's K/V
+    at ``layer`` in place and returns the stack; attention reads the
+    layer by indexing the stack, so no per-layer copy of the cache is
+    made. With ``page_table`` (B, pages_per_row) the leaves are a shared
+    page pool (R, n_pages, page_size, KVH, hd): the new token's K/V
+    scatter through the table and attention runs over the gathered
+    per-row view. Gathered masked positions contribute exactly 0
+    probability, so the result is bit-identical to the contiguous path
+    over the same tokens.
     """
     B, S, _ = x.shape
     q, k, v = qkv_proj(p, cfg, x, positions)
@@ -52,28 +57,34 @@ def attn_block(p, cfg, rt, x, positions, cache=None, lengths=None,
         assert S == 1
         qd = q[:, 0]  # (B,H,hd)
         k_cache, v_cache = cache
+        bidx = jnp.arange(B)
         if page_table is not None:
-            ps = k_cache.shape[1]
-            bidx = jnp.arange(B)
+            ps = k_cache.shape[2]
             page = page_table[bidx, lengths // ps]
             off = lengths % ps
-            k_cache = k_cache.at[page, off].set(k[:, 0])
-            v_cache = v_cache.at[page, off].set(v[:, 0])
+            k_cache = k_cache.at[layer, page, off].set(k[:, 0])
+            v_cache = v_cache.at[layer, page, off].set(v[:, 0])
             n_pt = page_table.shape[1]
-            k_view = k_cache[page_table].reshape(
-                B, n_pt * ps, *k_cache.shape[2:])
-            v_view = v_cache[page_table].reshape(
-                B, n_pt * ps, *v_cache.shape[2:])
+            # index the stack, not a slice of it: ``k_cache[layer]`` first
+            # would materialize the layer's whole pool
+            k_view = k_cache[layer, page_table].reshape(
+                B, n_pt * ps, *k_cache.shape[3:])
+            v_view = v_cache[layer, page_table].reshape(
+                B, n_pt * ps, *v_cache.shape[3:])
             o = decode_attention(qd, k_view, v_view, lengths + 1)
         elif rt.decode_kv_shard(cfg) == "seq":
-            o, k_cache, v_cache = seq_sharded_decode_attention(
-                qd, k_cache, v_cache, lengths, k[:, 0], v[:, 0],
-                rt.mesh, AXIS_MODEL)
+            o, k_l, v_l = seq_sharded_decode_attention(
+                qd, k_cache[layer], v_cache[layer], lengths, k[:, 0],
+                v[:, 0], rt.mesh, AXIS_MODEL)
+            k_cache = jax.lax.dynamic_update_index_in_dim(
+                k_cache, k_l, layer, 0)
+            v_cache = jax.lax.dynamic_update_index_in_dim(
+                v_cache, v_l, layer, 0)
         else:
-            bidx = jnp.arange(B)
-            k_cache = k_cache.at[bidx, lengths].set(k[:, 0])
-            v_cache = v_cache.at[bidx, lengths].set(v[:, 0])
-            o = decode_attention(qd, k_cache, v_cache, lengths + 1)
+            k_cache = k_cache.at[layer, bidx, lengths].set(k[:, 0])
+            v_cache = v_cache.at[layer, bidx, lengths].set(v[:, 0])
+            o = decode_attention(qd, k_cache[layer], v_cache[layer],
+                                 lengths + 1)
         o = o[:, None]  # (B,1,H,hd)
         new_cache = (k_cache, v_cache)
     else:
@@ -107,8 +118,12 @@ def attn_block(p, cfg, rt, x, positions, cache=None, lengths=None,
 
 
 def block_apply(p, cfg, rt, x, positions, i, *, cache=None, lengths=None,
-                decode=False, page_table=None):
+                decode=False, page_table=None, layer=None):
     """One block. cache: kind-dependent pytree (or None for training).
+
+    Decode takes the stacked cache of pattern position ``i`` (leading axis
+    R) and ``layer``, the index into it; it writes what this layer changed
+    at ``layer`` in place and returns the stack.
 
     Returns (x, new_cache, aux_losses dict).
     """
@@ -117,11 +132,19 @@ def block_apply(p, cfg, rt, x, positions, i, *, cache=None, lengths=None,
     if cfg.block_kind(i) == "attn":
         out, new_cache = attn_block(p["attn"], cfg, rt, h, positions,
                                     cache=cache, lengths=lengths,
-                                    decode=decode, page_table=page_table)
+                                    decode=decode, page_table=page_table,
+                                    layer=layer)
+    elif decode:
+        # SSM state is O(1) per row: read the layer's, write its new one
+        conv_state, ssm_state = jax.tree.map(
+            lambda c: jax.lax.dynamic_index_in_dim(c, layer, 0, False), cache)
+        out, new_l = mamba_apply(p["mamba"], cfg, h, conv_state=conv_state,
+                                 ssm_state=ssm_state, decode=True)
+        new_cache = jax.tree.map(
+            lambda c, n: jax.lax.dynamic_update_index_in_dim(c, n, layer, 0),
+            cache, new_l)
     else:
-        conv_state, ssm_state = cache if cache is not None else (None, None)
-        out, new_cache = mamba_apply(p["mamba"], cfg, h, conv_state=conv_state,
-                                     ssm_state=ssm_state, decode=decode)
+        out, new_cache = mamba_apply(p["mamba"], cfg, h)
     x = x + out
     if cfg.is_moe_layer(i):
         h = rmsnorm(p["norm2"], x, cfg.norm_eps)

@@ -197,8 +197,11 @@ class LM:
 
     def decode_backbone(self, params, rt: Runtime, x, lengths, caches,
                         page_table=None):
-        """One-token step through all layers, updating caches functionally.
+        """One-token step through all layers.
 
+        The stacked caches ride in the layer scan's carry, and each layer
+        writes only what it changed (one token of K/V, or its SSM state)
+        at its own index, in place: the step makes no copy of the caches.
         With ``page_table`` (B, pages_per_row) int32, attention cache
         leaves are a shared page pool (R, n_pages, page_size, KVH, hd)
         (see ``paged_cache_shapes``); SSM caches stay slot-indexed.
@@ -211,19 +214,19 @@ class LM:
                 "paged decode requires decode_kv_shard != 'seq' "
                 "(page tables gather across the sequence axis)")
 
-        def body(x, xs):
-            layer_params, layer_caches = xs
-            new_caches = {}
+        def body(carry, layer_params):
+            x, caches, li = carry
+            caches = dict(caches)
             for i in range(period):
                 pp = self._maybe_gather(rt, f"pos{i}", layer_params[f"pos{i}"])
-                x, cache_i, _ = block_apply(
+                x, caches[f"pos{i}"], _ = block_apply(
                     pp, cfg, rt, x, positions, i,
-                    cache=layer_caches[f"pos{i}"], lengths=lengths,
-                    decode=True, page_table=page_table)
-                new_caches[f"pos{i}"] = cache_i
-            return x, new_caches
+                    cache=caches[f"pos{i}"], lengths=lengths,
+                    decode=True, page_table=page_table, layer=li)
+            return (x, caches, li + 1), None
 
-        x, new_caches = jax.lax.scan(body, x, (params["blocks"], caches))
+        (x, new_caches, _), _ = jax.lax.scan(
+            body, (x, caches, jnp.int32(0)), params["blocks"])
         return x, new_caches
 
     # ------------------------------------------------------------- train

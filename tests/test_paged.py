@@ -279,6 +279,101 @@ def test_paged_engine_bitwise_matches_contiguous_engine(musicgen_lm):
     paged.pager.check_conservation()
 
 
+def _scan_output_lm(cfg):
+    """An ``LM`` whose decode step is an ``xs``/``ys`` layer scan: each
+    layer's caches go in as a scan input, sliced out of the stack, and
+    come back as a scan output, stacked anew. The same block math as
+    ``LM.decode`` (``block_apply`` over a one-layer stack); only where
+    the caches live differs, so its logits and caches are the reference
+    for the carried, in-place step."""
+    from repro.models.blocks import block_apply
+    from repro.models.lm import LM
+
+    class ScanOutputLM(LM):
+        def decode_backbone(self, params, rt, x, lengths, caches,
+                            page_table=None):
+            cfg, positions = self.cfg, lengths[:, None]
+
+            def body(x, xs):
+                layer_params, layer_caches = xs
+                new = {}
+                for i in range(cfg.pattern_period):
+                    one = jax.tree.map(lambda c: c[None],
+                                       layer_caches[f"pos{i}"])
+                    x, c, _ = block_apply(
+                        layer_params[f"pos{i}"], cfg, rt, x, positions, i,
+                        cache=one, lengths=lengths, decode=True,
+                        page_table=page_table, layer=0)
+                    new[f"pos{i}"] = jax.tree.map(lambda c: c[0], c)
+                return x, new
+
+            return jax.lax.scan(body, x, (params["blocks"], caches))
+    return ScanOutputLM(cfg)
+
+
+def _recorded(engine):
+    """Keep every decode step's logits and caches, copied to the host
+    before the next step donates them."""
+    decode, engine.record = engine._decode, []
+
+    def probed(*args):
+        logits, caches = decode(*args)
+        engine.record.append(jax.tree.map(np.asarray, (logits, caches)))
+        return logits, caches
+    engine._decode = probed
+    return engine
+
+
+@pytest.mark.parametrize("arch,page_size,kv_shard", [
+    ("granite-3-8b", 4, "auto"), ("granite-3-8b", None, "auto"),
+    ("granite-3-8b", None, "seq"), ("jamba-1.5-large-398b", 4, "auto"),
+    ("mamba2-1.3b", None, "auto"), ("musicgen-large", 4, "auto")])
+def test_in_place_decode_bitwise_matches_scan_output_reference(
+        arch, page_size, kv_shard):
+    """The decode step that writes each layer's cache update in place in
+    the carried stack returns the same logits and the same cache leaves,
+    bit for bit, as the step that stacks every layer's caches as a scan
+    output; over decode steps that cross page boundaries and a second
+    admission into a freed slot. ``kv_shard="seq"`` takes the
+    sequence-sharded attention, here on one device."""
+    from repro.configs import get_smoke_config
+    from repro.configs.base import ParallelConfig
+    from repro.models.lm import LM
+    from repro.serve.engine import Engine, Request
+
+    cfg = get_smoke_config(arch)
+    rt = LM(cfg).runtime(ParallelConfig(attn_q_chunk=16, attn_kv_chunk=16,
+                                        decode_kv_shard=kv_shard))
+    params = LM(cfg).init(jax.random.key(3))[0]
+    ncb = cfg.n_codebooks
+
+    def serve(lm):
+        eng = _recorded(Engine(lm, params, rt, max_batch=4, max_len=24,
+                               page_size=page_size))
+        r = np.random.default_rng(9)
+
+        def req(rid, plen, budget):
+            shape = (plen,) if ncb <= 1 else (plen, ncb)
+            return Request(rid=rid, tokens=r.integers(
+                1, cfg.vocab_size, shape).astype(np.int32),
+                max_new_tokens=budget)
+        eng.admit_many([req(0, 5, 3), req(1, 7, 8), req(2, 5, 6)])
+        for _ in range(3):
+            eng.step()
+        eng.admit_many([req(3, 7, 5), req(4, 5, 4)])
+        while eng.active:
+            eng.step()
+        return eng.record
+
+    got, want = serve(LM(cfg)), serve(_scan_output_lm(cfg))
+    assert len(got) == len(want) == 7
+    for step, (g, w) in enumerate(zip(got, want)):
+        assert jax.tree.structure(g) == jax.tree.structure(w)
+        for a, b in zip(jax.tree.leaves(g), jax.tree.leaves(w)):
+            assert (a.shape, a.dtype) == (b.shape, b.dtype), step
+            np.testing.assert_array_equal(a, b, err_msg=f"step {step}")
+
+
 def test_oversize_rejects_leak_neither_slots_nor_pages(musicgen_lm):
     """Satellite regression at engine scale (fails pre-fix): a mid-batch
     oversize request must be rejected individually — later requests still
