@@ -25,6 +25,19 @@ cache splices) per prefill group; ``engine.step`` holds
 chip) and ``engine.account`` (slot accounting). ``Engine.counters``
 counts prefill rows, padding included, and the padding rows.
 
+The engine holds its weights in the layout its decode program reads. At
+construction it lowers ``engine_decode`` with the parameters' layouts
+left to the compiler (:func:`decode_formats`), puts each leaf whose
+chosen format differs from the one it has into that format
+(:func:`hold_in`: new arrays, the caller's stay as they were), and jits
+``engine_decode`` and every ``engine_prefill`` against those formats, so
+the step reads each layer's weights where they lie instead of copying
+its slice of them into another layout every step.
+``counters["weights_relaid_bytes"]`` counts the bytes so placed: on a
+TPU the q, k and v projections of a dense transformer, whose head-major
+dot output wants them contraction dim minor; 0 where the compiler keeps
+every default (the CPU).
+
 MTC workflows (Montage-style DAGs of inference tasks) are driven by
 ``repro.core.tre.MTCRuntimeEnv``, which feeds this engine only tasks whose
 dependencies completed — the DawningCloud "trigger monitor" role. The env
@@ -35,11 +48,13 @@ finished requests are reported back via ``env.finish``) and
 """
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, field
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Format, Layout
 
 from repro import obs
 from repro.models.lm import LM, Runtime
@@ -56,6 +71,46 @@ class Request:
     rejected: bool = False        # oversize for the cache: never admitted
 
 
+def decode_formats(engine_decode, params, *args):
+    """The formats the compiler chooses for ``params`` when it compiles
+    ``engine_decode(params, *args)`` (the caches, ``args[2]``, donated)
+    with the parameters' layouts left to it and each leaf kept on its own
+    sharding; ``args`` keep the placement they have."""
+    auto = jax.tree.map(lambda x: Format(Layout.AUTO, x.sharding), params)
+    abstract = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding),
+        params)
+    compiled = jax.jit(engine_decode,
+                       in_shardings=(auto,) + (None,) * len(args),
+                       donate_argnums=(3,)).lower(abstract, *args).compile()
+    return compiled.input_formats[0][0]
+
+
+def relaid_bytes(params, formats) -> int:
+    """Bytes of the leaves of ``params`` not held in the format that
+    ``formats`` names for them."""
+    return sum(x.size * x.dtype.itemsize
+               for x, f in zip(jax.tree.leaves(params),
+                               jax.tree.leaves(formats))
+               if x.format != f)
+
+
+def hold_in(params, formats):
+    """``params`` with each leaf that is not in its format in ``formats``
+    copied into it; the other leaves, and the caller's arrays, as they
+    are. The program that copies is never written to JAX's persistent
+    compilation cache: loaded from there, it returns its result in the
+    default layout, whatever layout it was compiled for (jax 0.9)."""
+    key = "jax_persistent_cache_min_compile_time_secs"
+    was = getattr(jax.config, key)
+    jax.config.update(key, math.inf)
+    try:
+        return jax.tree.map(lambda x, f: x if x.format == f
+                            else jax.device_put(x, f), params, formats)
+    finally:
+        jax.config.update(key, was)
+
+
 class Engine:
     """``prefill_chunk``: run every grouped prefill at this fixed batch
     size (padding the final partial chunk) so the JIT specializes once per
@@ -70,19 +125,23 @@ class Engine:
         if prefill_chunk is not None and prefill_chunk < 1:
             raise ValueError("prefill_chunk must be >= 1")
         self.prefill_chunk = prefill_chunk
-        self.lm, self.params, self.rt = lm, params, rt
+        self.lm, self.rt = lm, rt
         self.max_batch, self.max_len = max_batch, max_len
         self.page_size = page_size
         self.lengths = jnp.zeros((max_batch,), jnp.int32)
         self.active: dict[int, Request] = {}     # slot -> request
         self.free = list(range(max_batch))
+        ncb = lm.cfg.n_codebooks
+        tok_shape = (max_batch,) if ncb <= 1 else (max_batch, ncb)
+        step_toks = jax.ShapeDtypeStruct(tok_shape[:1] + (1,) + tok_shape[1:],
+                                         jnp.int32)
         if page_size is None:
             self.pager = None
             self.caches = lm.init_cache(max_batch, max_len)
+            tables = ()
 
             def engine_decode(p, t, l, c):
                 return lm.decode(p, rt, t, l, c)
-            self._decode = jax.jit(engine_decode, donate_argnums=(3,))
         else:
             # physical paged KV: attention caches live in one shared page
             # pool; a slot's cache is the pages its table row maps. Page 0
@@ -105,18 +164,27 @@ class Engine:
             self.caches = lm.init_paged_cache(max_batch, n_pages, page_size)
             self._page_table = np.zeros((max_batch, self.pages_per_slot),
                                         np.int32)
+            tables = (self._page_table,)
 
             def engine_decode(p, t, l, c, pt):
                 return lm.decode(p, rt, t, l, c, page_table=pt)
-            self._decode = jax.jit(engine_decode, donate_argnums=(3,))
+        # the weights, once, in the layout the decode program reads; the
+        # prefill programs are compiled against it too
+        self.formats = decode_formats(engine_decode, params, step_toks,
+                                      self.lengths, self.caches, *tables)
+        self.params = hold_in(params, self.formats)
+        self._decode = jax.jit(
+            engine_decode, in_shardings=(self.formats, None, None, None)
+            + (None,) * len(tables), donate_argnums=(3,))
         self._prefill = {}
         self.steps = 0
         # rows run through prefill, and of them the padding rows that
-        # repeat a group's last request to fill ``prefill_chunk``
-        self.counters = {"prefill_rows": 0, "prefill_rows_padded": 0}
+        # repeat a group's last request to fill ``prefill_chunk``; bytes of
+        # weights held in a layout other than the one they came in
+        self.counters = {"prefill_rows": 0, "prefill_rows_padded": 0,
+                         "weights_relaid_bytes": relaid_bytes(params,
+                                                              self.formats)}
         # ---- vectorized slot accounting ----
-        ncb = lm.cfg.n_codebooks
-        tok_shape = (max_batch,) if ncb <= 1 else (max_batch, ncb)
         self._active_mask = np.zeros((max_batch,), bool)
         self._last_tok = np.zeros(tok_shape, np.int32)
         # generated tokens per slot (admit writes index 0; step appends).
@@ -139,48 +207,46 @@ class Engine:
         if key not in self._prefill:
             def engine_prefill(params, batch):
                 return self.lm.prefill(params, self.rt, batch)
-            self._prefill[key] = jax.jit(engine_prefill)
+            self._prefill[key] = jax.jit(engine_prefill,
+                                         in_shardings=(self.formats, None))
         return self._prefill[key]
 
     def _splice_caches(self, slot: int, pre_caches):
-        """Write a prefill cache (batch=1, seq=P) into the slot."""
-        def splice(dst, src):
-            # attn kv: src (R,1,P,KVH,hd) -> dst (R,B,S,KVH,hd) at [:,slot,:P]
-            # ssm conv: src (R,1,k-1,ch)  -> dst (R,B,k-1,ch)
-            # ssm state: src (R,1,nh,hp,ds) -> dst (R,B,nh,hp,ds)
-            src = src.astype(dst.dtype)
-            start = (0, slot) + (0,) * (dst.ndim - 2)
-            return jax.lax.dynamic_update_slice(dst, src, start)
+        """Write a prefill cache (batch=1, seq=P) into the slot.
 
-        if self.pager is None:
-            self.caches = jax.tree.map(splice, self.caches, pre_caches)
-            return
-        # paged: attention KV scatters page-sized chunks of the prefill
-        # into the slot's allocated pages; SSM state stays slot-indexed
-        ps = self.page_size
-        row = self._page_table[slot]
+        Each update replaces the engine's only reference to the leaf it
+        updates, so at most one leaf has a second version alive: the
+        updates are not donated, and the chip has no room for a second
+        cache beside the weights."""
+        paged = self.pager is not None
+        row = self._page_table[slot] if paged else None
 
-        def splice_paged(dst, src):
-            # src (R,1,P,KVH,hd) -> page-sized chunks into
-            # dst (R,n_pages,page_size,KVH,hd) at (0, row[j], 0, 0, 0)
-            src = src.astype(dst.dtype)
-            P = src.shape[2]
+        def updates(attn, src):
+            """(start, piece) pairs that write ``src`` into its leaf."""
+            if not (paged and attn):
+                # attn kv: src (R,1,P,KVH,hd) -> dst (R,B,S,KVH,hd) at
+                # [:,slot,:P]; ssm conv: src (R,1,k-1,ch) -> dst
+                # (R,B,k-1,ch); ssm state: src (R,1,nh,hp,ds) -> dst
+                # (R,B,nh,hp,ds)
+                yield (0, slot) + (0,) * (src.ndim - 2), src
+                return
+            # paged attention KV: page-sized chunks of src (R,1,P,KVH,hd)
+            # into dst (R,n_pages,page_size,KVH,hd) at (0, row[j], 0, 0, 0)
+            ps, P = self.page_size, src.shape[2]
             for j0 in range(0, P, ps):
-                cs = min(ps, P - j0)
-                chunk = jax.lax.dynamic_slice_in_dim(src, j0, cs, axis=2)
-                dst = jax.lax.dynamic_update_slice(
-                    dst, chunk, (0, int(row[j0 // ps]), 0, 0, 0))
-            return dst
+                yield ((0, int(row[j0 // ps]), 0, 0, 0),
+                       jax.lax.dynamic_slice_in_dim(src, j0,
+                                                    min(ps, P - j0), axis=2))
 
-        new = {}
-        for key, dst in self.caches.items():
-            i = int(key[3:])
-            if self.lm.cfg.block_kind(i) == "attn":
-                new[key] = tuple(splice_paged(d, s)
-                                 for d, s in zip(dst, pre_caches[key]))
-            else:
-                new[key] = jax.tree.map(splice, dst, pre_caches[key])
-        self.caches = new
+        for key in list(self.caches):
+            attn = self.lm.cfg.block_kind(int(key[3:])) == "attn"
+            leaves, tree = jax.tree.flatten(self.caches.pop(key))
+            for n, src in enumerate(jax.tree.leaves(pre_caches[key])):
+                src = src.astype(leaves[n].dtype)
+                for start, piece in updates(attn, src):
+                    leaves[n] = jax.lax.dynamic_update_slice(leaves[n], piece,
+                                                             start)
+            self.caches[key] = jax.tree.unflatten(tree, leaves)
 
     def admit(self, req: Request) -> bool:
         return bool(self.admit_many([req]))

@@ -38,9 +38,7 @@ def tiny():
 
 @pytest.fixture(scope="module", params=list(LAYOUTS))
 def engine(request, tiny):
-    lm, params, rt = tiny
-    return Engine(lm, params, rt, max_batch=8, max_len=48,
-                  prefill_chunk=CHUNK, page_size=LAYOUTS[request.param])
+    return build(tiny, request.param)
 
 
 @pytest.fixture(autouse=True)
@@ -55,6 +53,12 @@ def requests(lens, seed: int = 0, budget: int = 5) -> list[Request]:
                     tokens=rng.integers(1, CONFIG.vocab_size, n,
                                         dtype=np.int32))
             for i, n in enumerate(lens)]
+
+
+def build(tiny, layout):
+    lm, params, rt = tiny
+    return Engine(lm, params, rt, max_batch=8, max_len=48,
+                  prefill_chunk=CHUNK, page_size=LAYOUTS[layout])
 
 
 def served(engine, reqs) -> dict[int, list[int]]:
@@ -117,6 +121,101 @@ def test_counters_count_prefill_rows_and_padding(engine, lens, rows,
     assert engine.counters["prefill_rows"] - before["prefill_rows"] == rows
     assert (engine.counters["prefill_rows_padded"]
             - before["prefill_rows_padded"]) == padded
+
+
+def test_weights_stay_where_they_are_when_the_compiler_keeps_defaults(
+        tiny, engine):
+    """On the CPU the decode program keeps every parameter's default
+    layout: no weight is placed anew, and the engine serves from the
+    caller's own arrays."""
+    _, params, _ = tiny
+    assert engine.counters["weights_relaid_bytes"] == 0
+    assert all(a is b for a, b in zip(jax.tree.leaves(engine.params),
+                                      jax.tree.leaves(params)))
+
+
+@pytest.mark.parametrize("layout", list(LAYOUTS))
+def test_the_callers_params_serve_a_second_engine(tiny, layout):
+    """An engine takes nothing from the caller's weights: after one has
+    served, a second engine built from the same ``params`` serves the
+    same tokens."""
+    first = served(build(tiny, layout), requests(LENS, seed=4))
+    assert not any(x.is_deleted() for x in jax.tree.leaves(tiny[1]))
+    assert served(build(tiny, layout), requests(LENS, seed=4)) == first
+
+
+#: the q, k and v projections, which a TPU's decode dots read with the
+#: contraction dim minor
+QKV = ("wq", "wk", "wv")
+
+
+@pytest.fixture
+def transposed_qkv(monkeypatch):
+    """Make the engine's decode program choose the q, k and v projections
+    transposed, as a TPU's compiler lays them out, where the CPU's keeps
+    every default."""
+    from jax.experimental.layout import Format, Layout
+    from repro.serve import engine as engine_mod
+    chosen = engine_mod.decode_formats
+
+    def transposed(*args):
+        formats = chosen(*args)
+        attn = formats["blocks"]["pos0"]["attn"]
+        for w in QKV:
+            attn[w] = Format(Layout((0, 2, 1)), attn[w].sharding)
+        return formats
+    monkeypatch.setattr(engine_mod, "decode_formats", transposed)
+
+
+@pytest.mark.parametrize("layout", list(LAYOUTS))
+def test_weights_held_in_the_layout_decode_chose_serve_the_same_tokens(
+        tiny, layout, request):
+    """Where the decode program chooses another layout for some weights,
+    the engine holds copies of those in it, counts their bytes, compiles
+    decode and prefill against it, and serves the tokens an engine with
+    every default serves; the caller's arrays keep their layout."""
+    want = served(build(tiny, layout), requests(LENS, seed=5))
+    request.getfixturevalue("transposed_qkv")
+    eng = build(tiny, layout)
+    mine = tiny[1]["blocks"]["pos0"]["attn"]
+    held = eng.params["blocks"]["pos0"]["attn"]
+    assert eng.counters["weights_relaid_bytes"] == sum(
+        mine[w].nbytes for w in QKV)
+    for w in QKV:
+        assert held[w].format.layout.major_to_minor == (0, 2, 1)
+        assert mine[w].format.layout.major_to_minor == (0, 1, 2)
+        np.testing.assert_array_equal(held[w], mine[w])
+    assert held["wo"] is mine["wo"]
+    assert served(eng, requests(LENS, seed=5)) == want
+
+
+def test_weights_held_in_another_layout_survive_the_compilation_cache(
+        tiny, transposed_qkv, tmp_path):
+    """An engine built when every program it needs is in JAX's persistent
+    compilation cache still holds its weights in the chosen layout and
+    serves the same tokens (a copying program loaded from the cache
+    returns the default layout, and decode then refuses the weights)."""
+    from jax.experimental.compilation_cache import compilation_cache
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs")
+    was = {k: getattr(jax.config, k) for k in keys}
+    jax.config.update(keys[0], str(tmp_path))
+    jax.config.update(keys[1], 0.0)
+    compilation_cache.reset_cache()
+    try:
+        jax.clear_caches()                  # every program compiles, and
+        first = served(build(tiny, "paged"), requests(LENS, seed=6))
+        jax.clear_caches()                  # then comes from the disk
+        eng = build(tiny, "paged")
+        assert served(eng, requests(LENS, seed=6)) == first
+    finally:
+        for k, v in was.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
+    assert any(tmp_path.iterdir())
+    held = eng.params["blocks"]["pos0"]["attn"]
+    assert all(held[w].format.layout.major_to_minor == (0, 2, 1)
+               for w in QKV)
 
 
 def _module_name(jitted, *args) -> str:

@@ -374,6 +374,49 @@ def test_in_place_decode_bitwise_matches_scan_output_reference(
             np.testing.assert_array_equal(a, b, err_msg=f"step {step}")
 
 
+@pytest.mark.parametrize("arch,page_size", [
+    ("granite-3-8b", None), ("granite-3-8b", 4),
+    ("jamba-1.5-large-398b", 4)])
+def test_admission_keeps_one_version_of_each_cache_leaf(arch, page_size,
+                                                       monkeypatch):
+    """The splices that write a prefill into the cache are not donated,
+    so each makes a new version of the leaf it updates. The engine drops
+    the version it replaced before the next update: no more cache-shaped
+    arrays are alive at any update than before the admission, on the
+    contiguous cache, the page pool, and beside SSM state."""
+    from repro.configs import get_smoke_config
+    from repro.models.lm import LM
+    from repro.serve.engine import Engine, Request
+
+    cfg = get_smoke_config(arch)
+    lm = LM(cfg)
+    params = lm.init(jax.random.key(5))[0]
+    # 3 slots of 40: no prefill output or other test's cache has a cache
+    # leaf's shape
+    eng = Engine(lm, params, lm.runtime(), max_batch=3, max_len=40,
+                 page_size=page_size)
+    r = np.random.default_rng(2)
+
+    def admit():
+        eng.admit_many([Request(rid=i, max_new_tokens=2, tokens=r.integers(
+            1, cfg.vocab_size, (9,)).astype(np.int32)) for i in range(2)])
+    admit()                                 # compile prefill and decode
+    while eng.active:
+        eng.step()
+    leaf = {(x.shape, x.dtype) for x in jax.tree.leaves(eng.caches)}
+
+    def alive():
+        return sum((x.shape, x.dtype) in leaf for x in jax.live_arrays())
+    before, seen, update = alive(), [], jax.lax.dynamic_update_slice
+
+    def counted(*args):
+        seen.append(alive())
+        return update(*args)
+    monkeypatch.setattr(jax.lax, "dynamic_update_slice", counted)
+    admit()
+    assert len(seen) > 1 and max(seen) == before, (before, seen)
+
+
 def test_oversize_rejects_leak_neither_slots_nor_pages(musicgen_lm):
     """Satellite regression at engine scale (fails pre-fix): a mid-batch
     oversize request must be rejected individually — later requests still

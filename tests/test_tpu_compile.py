@@ -16,6 +16,7 @@ from __future__ import annotations
 import dataclasses
 import os
 import re
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +25,7 @@ import pytest
 from repro.configs.granite_3_8b import CONFIG as GRANITE
 from repro.configs.mamba2_1_3b import CONFIG as MAMBA2
 from repro.models.lm import LM
+from repro.serve.engine import decode_formats, relaid_bytes
 
 #: granite-3-8b at its published widths; 2 of its 40 layers keep each
 #: engine compile to seconds (the scanned layer body is the same program)
@@ -133,6 +135,44 @@ def granite(spec):
     return lm, lm.runtime(), spec(lm.init(None, abstract=True)[0])
 
 
+class Decode(NamedTuple):
+    default: object    # compiled with the parameters' default layouts
+    held: object       # the parameters' specs, in their default formats
+    formats: object    # what ``decode_formats`` chose for them
+    engine: object     # compiled against ``formats``, as the engine jits it
+    caches: object
+
+
+@pytest.fixture(scope="module")
+def decode(granite, spec):
+    """Per cache kind, the decode step compiled as the engine compiles it:
+    the formats the compiler chooses for the parameters, then the program
+    against them; beside it the program with every default layout."""
+    lm, rt, params = granite
+    toks, lengths = spec((SLOTS, 1), I32), spec((SLOTS,), I32)
+    cache = spec(lm.cache_shapes(SLOTS, MAX_LEN))
+    pool = spec(lm.paged_cache_shapes(SLOTS, 1 + SLOTS * MAX_LEN // PAGE,
+                                      PAGE))
+    kinds = {
+        "contiguous": (lambda p, t, l, c: lm.decode(p, rt, t, l, c),
+                       (toks, lengths, cache)),
+        "paged": (lambda p, t, l, c, pt: lm.decode(p, rt, t, l, c,
+                                                   page_table=pt),
+                  (toks, lengths, pool, spec((SLOTS, MAX_LEN // PAGE), I32))),
+    }
+    out = {}
+    for kind, (fn, args) in kinds.items():
+        default = _compile(fn, params, *args, donate_argnums=(3,))
+        held = jax.tree.map(
+            lambda x, f: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=f),
+            params, default.input_formats[0][0])
+        formats = decode_formats(fn, held, *args)
+        engine = _compile(fn, params, *args, donate_argnums=(3,),
+                          in_shardings=(formats,) + (None,) * len(args))
+        out[kind] = Decode(default, held, formats, engine, args[2])
+    return out
+
+
 def _whole_cache_writes(compiled, caches):
     """Instructions of ``compiled`` that copy, or update a slice of, a
     buffer shaped as a whole stacked cache leaf: each one rewrites every
@@ -157,36 +197,85 @@ def _check_in_place(compiled, caches):
     assert not _whole_cache_writes(compiled, caches)
 
 
-def test_engine_contiguous_decode_compiles(granite, spec):
+def test_engine_contiguous_decode_compiles(decode):
     """The contiguous step writes one token per row and layer into the
     donated cache, in place."""
-    lm, rt, params = granite
-    caches = lm.cache_shapes(SLOTS, MAX_LEN)
-    c = _compile(lambda p, t, l, c: lm.decode(p, rt, t, l, c),
-                 params, spec((SLOTS, 1), I32), spec((SLOTS,), I32),
-                 spec(caches), donate_argnums=(3,))
-    logits = c.out_info[0]
+    c = decode["contiguous"]
+    logits = c.engine.out_info[0]
     assert logits.shape == (SLOTS, GRANITE.vocab_padded)
-    _check_in_place(c, caches)
+    _check_in_place(c.engine, c.caches)
 
 
-def test_engine_paged_decode_compiles_within_memory(granite, spec):
+def test_engine_paged_decode_compiles_within_memory(decode):
     """The paged step scatters one token per row and layer into the
     donated page pool, in place."""
-    lm, rt, params = granite
-    n_pages = 1 + SLOTS * MAX_LEN // PAGE
-    pool = lm.paged_cache_shapes(SLOTS, n_pages, PAGE)
-    c = _compile(lambda p, t, l, c, pt: lm.decode(p, rt, t, l, c,
-                                                  page_table=pt),
-                 params, spec((SLOTS, 1), I32), spec((SLOTS,), I32),
-                 spec(pool), spec((SLOTS, MAX_LEN // PAGE), I32),
-                 donate_argnums=(3,))
-    _check_in_place(c, pool)
+    c = decode["paged"]
+    _check_in_place(c.engine, c.caches)
 
 
-def test_engine_prefill_compiles(granite, spec):
+_INSTRUCTION = re.compile(r"%\S+\s*=\s*(.*?)\s([a-z][\w-]*)\(")
+
+
+def _weight_relayouts(compiled, params):
+    """Instructions of ``compiled`` that copy or transpose a buffer shaped
+    as one layer's slice of a stacked weight matrix: each moves weights
+    and computes nothing."""
+    hlo = {"bfloat16": "bf16", "float32": "f32"}
+    shapes = {f"{hlo[x.dtype.name]}[1,{','.join(map(str, x.shape[1:]))}]"
+              for x in jax.tree.leaves(params["blocks"]) if x.ndim == 3}
+    found = []
+    for line in compiled.as_text().splitlines():
+        m = _INSTRUCTION.search(line)
+        if (m and m.group(2) in ("copy", "copy-start", "transpose")
+                and shapes & set(re.findall(r"\w+\[[0-9,]*\]", m.group(1)))):
+            found.append(line.strip()[:120])
+    return found
+
+
+@pytest.mark.parametrize("kind", ["contiguous", "paged"])
+def test_engine_decode_reads_weights_where_they_lie(decode, kind):
+    """With the parameters in their default layouts the step slices each
+    layer's q, k and v projections and copies them into the layout its
+    head-major dots read; the engine holds exactly those three in that
+    layout instead (100,663,296 bytes at 2 layers), and its step then
+    copies no layer's weights."""
+    c = decode[kind]
+    assert len(_weight_relayouts(c.default, c.held)) == 3
+    assert _weight_relayouts(c.engine, c.held) == []
+    paths = [jax.tree_util.keystr(path) for path, _ in
+             jax.tree_util.tree_flatten_with_path(c.held)[0]]
+    moved = [path for path, x, f in zip(paths, jax.tree.leaves(c.held),
+                                        jax.tree.leaves(c.formats))
+             if x.format != f]
+    assert moved == [f"['blocks']['pos0']['attn']['{w}']"
+                     for w in ("wk", "wq", "wv")]
+    assert relaid_bytes(c.held, c.formats) == 100_663_296
+
+
+@pytest.fixture(scope="module")
+def prefill(granite, spec, decode):
+    """Prefill, 4 x 1024, compiled with the default layouts and against
+    the formats the paged decode step chose, as the engine compiles it."""
     lm, rt, params = granite
-    c = _compile(lambda p, b: lm.prefill(p, rt, b), params,
-                 {"tokens": spec((4, 1024), I32)})
-    logits = c.out_info[0]
+    batch = {"tokens": spec((4, 1024), I32)}
+
+    def fn(p, b):
+        return lm.prefill(p, rt, b)
+    return (_compile(fn, params, batch),
+            _compile(fn, params, batch,
+                     in_shardings=(decode["paged"].formats, None)))
+
+
+def test_engine_prefill_compiles(prefill):
+    logits = prefill[1].out_info[0]
     assert logits.shape == (4, GRANITE.vocab_padded)
+
+
+def test_engine_prefill_takes_the_decode_formats_at_no_memory_cost(
+        granite, prefill):
+    """Prefill compiled against the decode step's formats needs no more
+    temporaries, and copies no layer's weights either."""
+    default, engine = prefill
+    assert _weight_relayouts(engine, granite[2]) == []
+    assert (engine.memory_analysis().temp_size_in_bytes
+            <= default.memory_analysis().temp_size_in_bytes)
