@@ -4,7 +4,6 @@
   fig9_11_params  Figs 9-11 (B/R parameter sweeps)
   fig12_14        Figs 12-14 (provider totals, peaks, adjustment overhead)
   tco             §4.5.5 TCO (DCS vs EC2-priced SSP)
-  roofline        §Roofline terms from the dry-run artifacts (launch/dryrun)
 
 ``python -m benchmarks.run [name ...]`` runs all (or the named) benchmarks.
 """
@@ -13,14 +12,13 @@ from __future__ import annotations
 import sys
 import time
 
-from benchmarks import fig9_11_params, fig12_14_provider, roofline, tables, tco
+from benchmarks import fig9_11_params, fig12_14_provider, tables, tco
 
 BENCHES = {
     "tables": tables.main,
     "fig9_11_params": fig9_11_params.main,
     "fig12_14": fig12_14_provider.main,
     "tco": tco.main,
-    "roofline": roofline.main,
 }
 
 

@@ -1,8 +1,8 @@
 """Config system: model architecture, input shapes, parallelism, run config.
 
 Plain frozen dataclasses — no external config library. Every assigned
-architecture file in this package exports ``CONFIG`` (full size, dry-run only)
-and ``SMOKE_CONFIG`` (reduced, runnable on CPU).
+architecture file in this package exports ``CONFIG`` (full size; on the CPU,
+abstract init only) and ``SMOKE_CONFIG`` (reduced, runnable on CPU).
 """
 from __future__ import annotations
 
@@ -110,7 +110,7 @@ class ModelConfig:
         assert self.n_layers % p == 0, (self.name, self.n_layers, p)
         return p
 
-    # ---- parameter counts (for roofline 6ND) ----
+    # ---- parameter counts (for 6ND FLOP counts) ----
     def param_count(self, active: bool = False) -> int:
         d, hd = self.d_model, self.head_dim
         total = self.vocab_size * d * (2 if self.n_codebooks <= 1 else 1 + self.n_codebooks)
@@ -154,7 +154,7 @@ class ShapeConfig:
         return self.seq_len * self.global_batch
 
 
-# The four assigned LM shape cells.
+# The four assigned LM shapes.
 SHAPES = {
     "train_4k": ShapeConfig("train_4k", "train", 4096, 256),
     "prefill_32k": ShapeConfig("prefill_32k", "prefill", 32768, 32),
@@ -166,18 +166,14 @@ SHAPES = {
 @dataclass(frozen=True)
 class ParallelConfig:
     """How a step is sharded on the mesh. Axes: (pod?, data, model)."""
-    strategy: str = "tp"          # tp | fsdp_tp  (param placement)
     zero1: bool = True            # shard optimizer state over data axis
     remat: str = "block"          # none | block | full
     microbatches: int = 1
-    moe_dispatch: str = "local"   # local (token-replicated) | a2a
     decode_kv_shard: str = "auto"  # auto | heads | seq
     attn_q_chunk: int = 512
     attn_kv_chunk: int = 1024
     attn_impl: str = "masked"     # masked (full pairs) | triangular (skip upper)
-    attn_seq_parallel: bool = False  # ring attention over the model axis
     grad_compress_pod: bool = False  # int8 cross-pod gradient all-reduce
-    pp_over_pod: bool = False        # pipeline the pod axis instead of DP
 
 
 @dataclass(frozen=True)

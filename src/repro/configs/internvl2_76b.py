@@ -1,7 +1,7 @@
 """InternVL2 76B: InternViT (stub frontend) + LLaMA3-70B-class backbone.
 
 [arXiv:2404.16821; unverified] 80L d_model=8192 64H (GQA kv=8) d_ff=28672
-vocab=128256. The vision tower is a STUB: input_specs() provides
+vocab=128256. The vision tower is a STUB: the data pipeline provides
 precomputed patch embeddings at d_model.
 """
 from repro.configs.base import ModelConfig, smoke_reduce

@@ -1,1 +1,1 @@
-from repro.data.synthetic import synthetic_batches, input_specs  # noqa: F401
+from repro.data.synthetic import synthetic_batches  # noqa: F401

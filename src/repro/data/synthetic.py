@@ -1,69 +1,21 @@
-"""Deterministic synthetic token pipeline + abstract input specs.
+"""Deterministic synthetic token pipeline.
 
-``input_specs(model, shape)`` is the single source of truth for what a step
-consumes — the dry-run lowers against these ShapeDtypeStructs and the
-synthetic pipeline materializes matching concrete batches for smoke tests
-and end-to-end examples (with the MusicGen delay pattern applied to
-codebook streams, and stub patch embeddings for the VLM).
+``synthetic_batches`` materializes seeded concrete training batches for
+smoke tests and end-to-end examples (with the MusicGen delay pattern
+applied to codebook streams, and stub patch embeddings for the VLM).
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.base import ModelConfig, RunConfig, ShapeConfig
+from repro.configs.base import ModelConfig, RunConfig
 
 
 def _token_shape(cfg: ModelConfig, B: int, S: int) -> tuple:
     if cfg.n_codebooks > 1:
         return (B, S, cfg.n_codebooks)
     return (B, S)
-
-
-def train_input_specs(cfg: ModelConfig, shape: ShapeConfig) -> dict:
-    B, S = shape.global_batch, shape.seq_len
-    S_txt = S - cfg.n_patches if cfg.vision_stub else S
-    i32 = jnp.int32
-    specs = {
-        "tokens": jax.ShapeDtypeStruct(_token_shape(cfg, B, S_txt), i32),
-        "targets": jax.ShapeDtypeStruct(_token_shape(cfg, B, S_txt), i32),
-        "mask": jax.ShapeDtypeStruct((B, S_txt), jnp.float32),
-    }
-    if cfg.vision_stub:
-        specs["patches"] = jax.ShapeDtypeStruct(
-            (B, cfg.n_patches, cfg.d_model), jnp.dtype(cfg.dtype))
-    return specs
-
-
-def prefill_input_specs(cfg: ModelConfig, shape: ShapeConfig) -> dict:
-    B, S = shape.global_batch, shape.seq_len
-    S_txt = S - cfg.n_patches if cfg.vision_stub else S
-    specs = {"tokens": jax.ShapeDtypeStruct(_token_shape(cfg, B, S_txt),
-                                            jnp.int32)}
-    if cfg.vision_stub:
-        specs["patches"] = jax.ShapeDtypeStruct(
-            (B, cfg.n_patches, cfg.d_model), jnp.dtype(cfg.dtype))
-    return specs
-
-
-def decode_input_specs(cfg: ModelConfig, shape: ShapeConfig) -> dict:
-    """One new token against a cache of capacity shape.seq_len."""
-    B = shape.global_batch
-    return {
-        "tokens": jax.ShapeDtypeStruct(_token_shape(cfg, B, 1), jnp.int32),
-        "lengths": jax.ShapeDtypeStruct((B,), jnp.int32),
-    }
-
-
-def input_specs(cfg: ModelConfig, shape: ShapeConfig) -> dict:
-    if shape.kind == "train":
-        return train_input_specs(cfg, shape)
-    if shape.kind == "prefill":
-        return prefill_input_specs(cfg, shape)
-    if shape.kind == "decode":
-        return decode_input_specs(cfg, shape)
-    raise ValueError(shape.kind)
 
 
 def apply_delay_pattern(tokens: np.ndarray, pad: int = 0) -> np.ndarray:

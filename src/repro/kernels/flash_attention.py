@@ -14,7 +14,7 @@ Causality is handled two ways at once:
   - the diagonal block applies an element mask.
 
 The jnp oracle lives in kernels/ref.py; repro.models.attention is the
-model-side equivalent used under jit/dry-run.
+model-side equivalent used under jit.
 """
 from __future__ import annotations
 

@@ -1,21 +1,13 @@
-"""Production mesh construction.
+"""Mesh construction.
 
 Importing this module never touches jax device state; meshes are built only
-inside the factory functions. The production target is TPU v5e:
-one pod = 16x16 = 256 chips, multi-pod = 2 pods = 512 chips.
+inside ``make_mesh``.
 """
 from __future__ import annotations
 
 import jax
 
 from repro.parallel.sharding import AXIS_DATA, AXIS_MODEL, AXIS_POD
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = (AXIS_POD, AXIS_DATA, AXIS_MODEL) if multi_pod else (AXIS_DATA,
-                                                                AXIS_MODEL)
-    return jax.make_mesh(shape, axes)
 
 
 def make_mesh(data: int, model: int, pod: int = 1):

@@ -2,8 +2,7 @@
 
 Runs the fault-tolerant elastic loop on the selected architecture. With
 ``--smoke`` (default) the reduced config runs on local devices; without it
-the full assigned config is used (expects a real TPU pod — on CPU use the
-dry-run instead).
+the full assigned config is used (expects a real TPU pod).
 """
 from __future__ import annotations
 

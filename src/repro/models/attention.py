@@ -6,7 +6,7 @@ Three execution paths:
 - ``triangular``  scan over the *static lower-triangular list* of chunk pairs
                   — true causal FLOPs in pure JAX (beyond-paper §Perf opt).
 - Pallas flash kernel (repro.kernels) on real TPUs; the jnp paths double as
-  its oracle and as the dry-run-lowered implementation.
+  its oracle and as the model-side implementation.
 
 Decode uses grouped-query einsums against the KV cache without materializing
 repeated KV heads; the sequence-sharded combine lives in

@@ -88,20 +88,12 @@ def attn_block(p, cfg, rt, x, positions, cache=None, lengths=None,
         o = o[:, None]  # (B,1,H,hd)
         new_cache = (k_cache, v_cache)
     else:
-        if rt.parallel.attn_seq_parallel and rt.mesh is not None:
-            # ring attention: sequence-parallel over the model axis; the
-            # unrepeated GQA kv shards rotate via collective_permute
-            from repro.parallel.collectives import ring_attention
-            o = ring_attention(q, k, v, rt.mesh, AXIS_MODEL, causal=True)
-            out = jnp.einsum("bsq,qd->bsd", o.reshape(B, S, cfg.q_dim),
-                             p["wo"])
-            return out, (k, v)
         kf = repeat_kv(k, cfg.n_heads)
         vf = repeat_kv(v, cfg.n_heads)
         # pad heads to the model-axis multiple so the chunked scans stay
         # collective-free (padded heads are dead weight, sliced off below)
         H = cfg.n_heads
-        Hp = rt.padded_heads(H) if hasattr(rt, "padded_heads") else H
+        Hp = rt.padded_heads(H)
         if Hp != H:
             pad = ((0, 0), (0, 0), (0, Hp - H), (0, 0))
             q, kf, vf = (jnp.pad(t, pad) for t in (q, kf, vf))

@@ -15,7 +15,6 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass, field
-from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -24,10 +23,8 @@ from jax.sharding import Mesh
 from repro.configs.base import ModelConfig, ParallelConfig
 from repro.models.blocks import block_apply, block_init
 from repro.models.layers import rmsnorm, rmsnorm_init
-from repro.models.module import (
-    Scope, init_with_axes, is_axes_leaf, stacked_init, strip_stack_axis, _fold,
-)
-from repro.parallel.sharding import AXIS_MODEL, batch_axes, resolve_spec
+from repro.models.module import Scope, stacked_init, _fold
+from repro.parallel.sharding import AXIS_MODEL, batch_axes
 
 AUX_KEYS = ("moe_lb_loss", "moe_z_loss")
 
@@ -37,7 +34,6 @@ class Runtime:
     """Static execution context threaded through apply fns."""
     parallel: ParallelConfig = field(default_factory=ParallelConfig)
     mesh: Mesh | None = None
-    block_axes: Any = None  # per-pattern-pos axes trees (fsdp re-gather)
 
     def moe_mesh(self):
         return self.mesh
@@ -130,9 +126,7 @@ class LM:
         return params, axes
 
     def runtime(self, parallel=None, mesh=None):
-        _, axes = self.init(None, abstract=True)
-        block_axes = {k: strip_stack_axis(v) for k, v in axes["blocks"].items()}
-        return Runtime(parallel or ParallelConfig(), mesh, block_axes)
+        return Runtime(parallel or ParallelConfig(), mesh)
 
     # ------------------------------------------------------------ embed
     def embed(self, params, batch):
@@ -156,23 +150,6 @@ class LM:
         return jnp.einsum("bsd,dv->bsv", x, params["head"][0])
 
     # ---------------------------------------------------------- backbone
-    def _maybe_gather(self, rt: Runtime, pos: str, p_slice):
-        """FSDP: re-gather a storage-sharded block slice to the TP layout."""
-        if (rt.mesh is None or rt.parallel.strategy != "fsdp_tp"
-                or rt.block_axes is None):
-            return p_slice
-        mesh = rt.mesh
-        leaves, treedef = jax.tree.flatten(p_slice)
-        axes_leaves = jax.tree.leaves(rt.block_axes[pos], is_leaf=is_axes_leaf)
-        assert len(leaves) == len(axes_leaves)
-        out = [
-            jax.lax.with_sharding_constraint(
-                p, jax.sharding.NamedSharding(
-                    mesh, resolve_spec(a, p.shape, mesh, "tp")))
-            for p, a in zip(leaves, axes_leaves)
-        ]
-        return jax.tree.unflatten(treedef, out)
-
     def backbone(self, params, rt: Runtime, x, positions, *, collect_cache=False,
                  remat=True):
         cfg = self.cfg
@@ -182,8 +159,8 @@ class LM:
             x, aux = carry
             caches = {}
             for i in range(period):
-                pp = self._maybe_gather(rt, f"pos{i}", layer_params[f"pos{i}"])
-                x, cache_i, aux_i = block_apply(pp, cfg, rt, x, positions, i)
+                x, cache_i, aux_i = block_apply(
+                    layer_params[f"pos{i}"], cfg, rt, x, positions, i)
                 x = rt.shard_activations(x)
                 caches[f"pos{i}"] = cache_i
                 aux = _acc_aux(aux, aux_i)
@@ -218,9 +195,8 @@ class LM:
             x, caches, li = carry
             caches = dict(caches)
             for i in range(period):
-                pp = self._maybe_gather(rt, f"pos{i}", layer_params[f"pos{i}"])
                 x, caches[f"pos{i}"], _ = block_apply(
-                    pp, cfg, rt, x, positions, i,
+                    layer_params[f"pos{i}"], cfg, rt, x, positions, i,
                     cache=caches[f"pos{i}"], lengths=lengths,
                     decode=True, page_table=page_table, layer=li)
             return (x, caches, li + 1), None

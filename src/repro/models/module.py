@@ -5,9 +5,8 @@ and registers parameters with *logical axis* annotations; the scope builds
 two parallel pytrees: ``params`` (arrays) and ``axes`` (tuples of logical
 axis names, consumed by repro.parallel.sharding).
 
-``abstract=True`` scopes produce ``jax.ShapeDtypeStruct`` leaves — this is
-how the multi-pod dry-run gets parameter shapes/shardings for trillion-param
-configs without allocating a single byte.
+``abstract=True`` scopes produce ``jax.ShapeDtypeStruct`` leaves — parameter
+shapes for trillion-param configs without allocating a single byte.
 """
 from __future__ import annotations
 
@@ -97,11 +96,6 @@ def stacked_init(init_fn, key, n: int, dtype=jnp.bfloat16, abstract=False,
     axes = jax.tree.map(lambda a: (stack_axis_name,) + a, axes,
                         is_leaf=is_axes_leaf)
     return params, axes
-
-
-def strip_stack_axis(axes_tree):
-    """Remove the leading 'layers' logical axis (for per-slice specs)."""
-    return jax.tree.map(lambda a: a[1:], axes_tree, is_leaf=is_axes_leaf)
 
 
 def cast_tree(tree, dtype):

@@ -3,7 +3,7 @@
 The chunked dual form is TPU-native: within-chunk attention-like einsums hit
 the MXU, the inter-chunk state pass is a short ``lax.scan``. The Pallas
 ``ssd_scan`` kernel mirrors the same blocking; this jnp path is its oracle and
-the dry-run implementation.
+the model-side implementation.
 
 Sharding: d_inner (heads) over ``model``; B/C projections are group-shared
 (MQA-like, ``ssm_groups``) and replicated.

@@ -5,5 +5,4 @@ from repro.parallel.sharding import (  # noqa: F401
     batch_axes,
     logical_rules,
     resolve_spec,
-    spec_tree,
 )

@@ -6,15 +6,6 @@ each chip computes a partial softmax over its KV slice, and the partials are
 combined with one tiny ``psum`` (per-head scalars + one head-dim vector).
 This is what lets a 524k-token cache decode on a 16-way axis, and lets GQA
 archs with kv_heads < axis size shard their cache at all.
-
-``ring_attention`` is sequence-parallel prefill attention: q/k/v are
-sharded along *sequence* over a mesh axis, every chip computes its local
-q block against the kv shard it currently holds, and kv rotates around the
-ring via ``collective_permute`` — total wire per chip = one pass of the kv
-shards ((n-1)/n x kv bytes) instead of the head-parallel formulation's
-output all-reduce (2(n-1)/n x activation bytes, which is ~d_model/kv_dim
-times larger for GQA models). Online-softmax accumulators merge the per-
-shard partials exactly (same math as the flash kernel).
 """
 from __future__ import annotations
 
@@ -25,74 +16,6 @@ from jax.sharding import PartitionSpec as P
 from repro.parallel.sharding import AXIS_MODEL, batch_axes
 
 NEG_INF = -1e30
-
-
-def _ring_body(q, k, v, *, axis: str, causal: bool):
-    """Per-shard body. q: (B, S_loc, H, hd); k/v: (B, S_loc, KVH, hd) —
-    the ring rotates the *unrepeated* GQA kv shards (kv_dim bytes per hop,
-    not H x hd: 8x less wire for the kv=8 archs)."""
-    n = jax.lax.axis_size(axis)
-    idx = jax.lax.axis_index(axis)
-    B, Sl, H, hd = q.shape
-    KVH = k.shape[2]
-    G = H // KVH
-    scale = 1.0 / (hd ** 0.5)
-    qg = q.astype(jnp.float32).reshape(B, Sl, KVH, G, hd)
-    perm = [(j, (j + 1) % n) for j in range(n)]
-
-    def step(i, carry):
-        k, v, o, m, l = carry
-        src = (idx - i) % n                   # whose kv shard we hold now
-        s = jnp.einsum("bqhgd,bkhd->bhgqk", qg,
-                       k.astype(jnp.float32)) * scale
-        if causal:
-            qpos = idx * Sl + jnp.arange(Sl)
-            kpos = src * Sl + jnp.arange(Sl)
-            mask = qpos[:, None] >= kpos[None, :]
-            s = jnp.where(mask[None, None, None], s, NEG_INF)
-        mb = jnp.max(s, axis=-1)
-        mn = jnp.maximum(m, mb)
-        alpha = jnp.exp(m - mn)
-        p = jnp.exp(s - mn[..., None])
-        o = o * alpha[..., None] + jnp.einsum(
-            "bhgqk,bkhd->bhgqd", p.astype(v.dtype), v).astype(jnp.float32)
-        l = l * alpha + jnp.sum(p, axis=-1)
-        k = jax.lax.ppermute(k, axis, perm)
-        v = jax.lax.ppermute(v, axis, perm)
-        return (k, v, o, mn, l)
-
-    o0 = jnp.zeros((B, KVH, G, Sl, hd), jnp.float32)
-    m0 = jnp.full((B, KVH, G, Sl), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((B, KVH, G, Sl), jnp.float32)
-    _, _, o, m, l = jax.lax.fori_loop(0, n, step, (k, v, o0, m0, l0))
-    out = o / jnp.maximum(l, 1e-30)[..., None]        # (B,KVH,G,Sl,hd)
-    return out.transpose(0, 3, 1, 2, 4).reshape(B, Sl, H, hd).astype(q.dtype)
-
-
-def ring_attention(q, k, v, mesh, axis=AXIS_MODEL, *, causal=True):
-    """Sequence-parallel attention. q: (B,S,H,hd); k/v: (B,S,KVH,hd)
-    *unrepeated*; S shards over ``axis``. Returns (B,S,H,hd)."""
-    S = q.shape[1]
-    if (mesh is None or mesh.shape.get(axis, 1) == 1
-            or S % mesh.shape[axis] != 0):
-        return _fallback_full(q, k, v, causal)
-    bax = batch_axes(mesh)
-    btotal = 1
-    for a in bax:
-        btotal *= mesh.shape[a]
-    b = bax if (bax and q.shape[0] % btotal == 0) else None
-    spec = P(b, axis, None, None)
-    fn = jax.shard_map(
-        lambda qq, kk, vv: _ring_body(qq, kk, vv, axis=axis, causal=causal),
-        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_vma=False)
-    return fn(q, k, v)
-
-
-def _fallback_full(q, k, v, causal):
-    from repro.models.attention import chunked_attention, repeat_kv
-    return chunked_attention(q, repeat_kv(k, q.shape[2]),
-                             repeat_kv(v, q.shape[2]), causal=causal)
 
 
 def _partial_decode(q, k, v, lengths, new_k, new_v, axis, seq_total):

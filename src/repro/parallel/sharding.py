@@ -10,10 +10,9 @@ Strategies
 ----------
 ``tp``       params sharded over ``model`` only (Megatron TP); activations
              sharded over batch (``data``/``pod``) and heads/mlp (``model``).
-``fsdp_tp``  additionally shards the ``embed``/``expert_in`` logical axes over
-             ``data`` for *storage*; the per-layer scan body re-gathers to the
-             ``tp`` layout (ZeRO-3 / FSDP). Optimizer state inherits storage
-             sharding.
+``fsdp_tp``  additionally shards the ``embed`` logical axis over ``data``
+             (and ``pod`` on a multi-pod mesh): the FSDP / ZeRO storage
+             layout.
 """
 from __future__ import annotations
 
@@ -45,8 +44,7 @@ _TP_RULES: dict[str, object] = {
 }
 
 _FSDP_EXTRA: dict[str, object] = {
-    # storage-only: re-gathered per scan step; on the multi-pod mesh the
-    # pod axis joins the shard (1T-param optimizer state needs 32-way)
+    # storage layout; on the multi-pod mesh the pod axis joins the shard
     "embed": ("pod", "data"),
 }
 
@@ -108,22 +106,6 @@ def resolve_spec(
     while out and out[-1] is None:
         out.pop()
     return P(*out)
-
-
-def spec_tree(axes_tree, shape_tree, mesh: Mesh, strategy: str = "tp"):
-    """Map a pytree of logical-axes tuples (+ matching shapes) to PartitionSpecs."""
-    return jax.tree.map(
-        lambda axes, shp: resolve_spec(tuple(axes), tuple(shp), mesh, strategy),
-        axes_tree,
-        shape_tree,
-        is_leaf=lambda x: isinstance(x, tuple) and all(isinstance(a, (str, type(None))) for a in x),
-    )
-
-
-def sharding_tree(axes_tree, shape_tree, mesh: Mesh, strategy: str = "tp"):
-    specs = spec_tree(axes_tree, shape_tree, mesh, strategy)
-    return jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
-                        is_leaf=lambda x: isinstance(x, P))
 
 
 def constrain(x, mesh: Mesh, *axes):

@@ -1,14 +1,8 @@
 """AdamW with distributed state sharding (built from scratch — no optax).
 
 Moments default to bf16 so a 1T-param model's optimizer state is 3x params
-(bf16 p + m + v) instead of 12x — combined with FSDP/ZeRO sharding over the
-``data`` axis this is what lets kimi-k2 train on 512 v5e chips. Update math
-runs in fp32 regardless of storage dtype.
-
-ZeRO-1: even when params use plain TP placement, optimizer-state *storage*
-specs are resolved under the ``fsdp_tp`` rule table (extra ``data``-axis
-sharding). GSPMD then turns the grad all-reduce into reduce-scatter + update
-+ all-gather — the canonical ZeRO-1 dataflow — without manual collectives.
+(bf16 p + m + v) instead of 12x. Update math runs in fp32 regardless of
+storage dtype. The state is sharded however its arrays are placed.
 """
 from __future__ import annotations
 

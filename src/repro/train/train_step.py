@@ -1,26 +1,20 @@
-"""Train-step builder: value_and_grad + microbatch accumulation + sharding.
+"""Train-step builder: value_and_grad + microbatch accumulation.
 
-``build_train_step`` returns (step_fn, state_shardings, batch_sharding) so
-the launcher / dry-run can jit with explicit in/out shardings. Gradient
-accumulation scans over microbatches with bf16 accumulators kept in the
-optimizer-state (ZeRO) sharding, deferring the cross-``data`` reduction to
-the weight update — the accumulation itself adds no collectives.
+``build_train_step`` returns (step_fn, runtime, optimizer). Gradient
+accumulation scans over microbatches with bf16 accumulators, deferring the
+cross-``data`` reduction to the weight update — the accumulation itself
+adds no collectives.
 
 Cross-pod gradient compression (int8 + error feedback) is available for the
 multi-pod mesh via ``ParallelConfig.grad_compress_pod``.
 """
 from __future__ import annotations
 
-from functools import partial
-
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import RunConfig
-from repro.models.lm import LM, Runtime
-from repro.models.module import is_axes_leaf
-from repro.parallel.sharding import batch_axes, resolve_spec
+from repro.models.lm import LM
 from repro.train.optimizer import AdamW, TrainState
 
 
@@ -30,36 +24,6 @@ def make_optimizer(rcfg: RunConfig) -> AdamW:
         eps=rcfg.adam_eps, weight_decay=rcfg.weight_decay,
         grad_clip=rcfg.grad_clip, warmup_steps=rcfg.warmup_steps,
         total_steps=rcfg.total_steps, moment_dtype=rcfg.moment_dtype)
-
-
-def state_specs(lm: LM, axes, mesh, parallel):
-    """PartitionSpecs for TrainState: params per strategy; moments ZeRO'd."""
-    param_strategy = parallel.strategy
-    opt_strategy = "fsdp_tp" if (parallel.zero1 or
-                                 parallel.strategy == "fsdp_tp") else "tp"
-
-    def resolve(tree_axes, shapes, strategy):
-        leaves_a = jax.tree.leaves(tree_axes, is_leaf=is_axes_leaf)
-        leaves_s, treedef = jax.tree.flatten(shapes)
-        specs = [resolve_spec(a, s.shape, mesh, strategy)
-                 for a, s in zip(leaves_a, leaves_s)]
-        return jax.tree.unflatten(treedef, specs)
-
-    abstract_params, _ = lm.init(None, abstract=True)
-    p_specs = resolve(axes, abstract_params, param_strategy)
-    o_specs = resolve(axes, abstract_params, opt_strategy)
-    return TrainState(step=P(), params=p_specs, m=o_specs, v=o_specs)
-
-
-def batch_spec(mesh):
-    return P(batch_axes(mesh) or None)
-
-
-def batch_shardings(mesh, batch_tree):
-    bs = batch_spec(mesh)
-    def spec_for(x):
-        return NamedSharding(mesh, P(*bs, *([None] * (len(x.shape) - 1))))
-    return jax.tree.map(spec_for, batch_tree)
 
 
 def build_train_step(lm: LM, rcfg: RunConfig, mesh=None):

@@ -1,5 +1,5 @@
 """Shared test fixtures. NOTE: no XLA_FLAGS here — smoke tests and benches
-must see the real single CPU device; only launch/dryrun.py forces 512.
+must see the real single CPU device.
 
 Also provides a ``hypothesis`` degradation shim: property-based tests import
 ``given``/``settings``/``st`` from here instead of from ``hypothesis``

@@ -24,7 +24,7 @@ def test_full_config_matches_assignment(arch):
     cfg = get_config(arch)
     assert cfg.name == arch
     assert cfg.param_count() > 0
-    # every full config must be dry-runnable (abstract init only)
+    # every full config must init abstractly (no allocation)
     params, axes = LM(cfg).init(None, abstract=True)
     assert jax.tree.all(jax.tree.map(
         lambda p: isinstance(p, jax.ShapeDtypeStruct), params))

@@ -627,7 +627,10 @@ def test_vectorized_accounting_matches_loop_reference(ops, extra):
 @settings(max_examples=60)
 def test_admission_queue_drains_fifo_fair(needs, capacity):
     """First-come: deferred requests complete in submission order — a
-    later request never completes before an earlier one (quotas unset)."""
+    later request never completes before an earlier one (quotas unset).
+    Needs that fit the pool are all granted; needs beyond it are granted
+    in full as a prefix in submission order, the head after that prefix
+    takes what the pool has left, and the rest stay queued."""
     prov = ResourceProvider(capacity, coordination="first-come")
     prov.request("hog", capacity, 0.0)
     order: list[int] = []
@@ -647,7 +650,17 @@ def test_admission_queue_drains_fifo_fair(needs, capacity):
         if prov.allocated.get("hog", 0) > 0:
             prov.release("hog", 1, 100.0 + step)
     assert order == sorted(order)
-    assert all(r.status == "granted" for r in reqs)
+    if sum(needs) <= capacity:
+        assert all(r.status == "granted" for r in reqs)
+        return
+    full = [r.status == "granted" for r in reqs]
+    k = full.index(False)
+    assert not any(full[k:])
+    assert [r.granted for r in reqs[:k]] == needs[:k]
+    assert all(r.status == "queued" and r.granted < n
+               for r, n in zip(reqs[k:], needs[k:]))
+    # work-conserving: the pool the hog gave back is drawn in full
+    assert sum(r.granted for r in reqs) == capacity
 
 
 # -------------------------------------------------- drain re-entrancy

@@ -419,7 +419,14 @@ def test_fleet_rejects_duplicate_jids_and_capacity_mismatch():
 def test_property_fleet_partitioning(specs, capacity, coordination):
     """For all tick sequences: ``sum(tenant.active) <= engine.capacity``
     and ``tenant.active <= tenant.granted`` per tenant — and every task
-    of every tenant completes with zero over-admissions."""
+    of every tenant completes with zero over-admissions. A fleet whose
+    tenants' initial nodes exceed the pool is refused at creation."""
+    if len(specs) * FLEET_POLICY.initial > capacity:
+        with pytest.raises(RuntimeError, match="initial resources rejected"):
+            RecordingFleet(_tenant_dags(specs),
+                           engine=EmulatedEngine(capacity),
+                           coordination=coordination, policies=FLEET_POLICY)
+        return
     fleet = RecordingFleet(_tenant_dags(specs),
                            engine=EmulatedEngine(capacity),
                            coordination=coordination, policies=FLEET_POLICY)
@@ -428,6 +435,19 @@ def test_property_fleet_partitioning(specs, capacity, coordination):
     assert fs.over_admissions == 0 and fs.isolation_violations == 0
     assert fleet.provider.total_allocated == 0
     _assert_partition_property(fleet)
+
+
+@pytest.mark.parametrize("coordination", ["first-come", "coordinated"])
+def test_fleet_refuses_a_tenant_beyond_the_pool(coordination):
+    """Four tenants each need one initial node from a three-node pool: the
+    §3.1.3 creation path refuses the fourth TRE, so the fleet is not
+    built."""
+    with pytest.raises(RuntimeError,
+                       match="TRE 'serve-fleet-t3': initial resources "
+                             "rejected"):
+        ServeFleet(_tenant_dags([[(1, 0)]] * 4),
+                   engine=EmulatedEngine(3), coordination=coordination,
+                   policies=FLEET_POLICY)
 
 
 def test_fleet_partitioning_deterministic():

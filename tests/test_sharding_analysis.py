@@ -1,18 +1,13 @@
-"""Sharding rule resolution + HLO collective analysis unit tests.
+"""Sharding rule resolution unit tests.
 
 These run on the single CPU device (no mesh construction with >1 device
 needed: Mesh objects over 1 device still exercise the rule logic via a
 fake mesh namespace)."""
 from __future__ import annotations
 
-import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.launch.hlo_analysis import (
-    CollectiveOp, _parse_groups, _shape_bytes, collective_summary,
-    parse_collectives, scale_by_loops,
-)
 from repro.parallel.sharding import resolve_spec
 
 
@@ -64,60 +59,3 @@ def test_unknown_strategy_raises():
     with pytest.raises(ValueError):
         resolve_spec(("embed",), (64,), POD_MESH, "zeRO9")
 
-
-# ------------------------------------------------------------ hlo analysis
-def test_shape_bytes():
-    assert _shape_bytes("bf16[8,512]{1,0}") == 8 * 512 * 2
-    assert _shape_bytes("(f32[4], s32[2])") == 16 + 8
-    assert _shape_bytes("pred[10]") == 10
-
-
-SAMPLE_HLO = """\
-ENTRY %main.1 (p0: bf16[16,512]) -> bf16[16,512] {
-  %w = bf16[16,512]{1,0} while(%t), condition=%cond.1, body=%body.1
-  %ar0 = f32[1024]{0} all-reduce(%x), replica_groups={{0,1,2,3}}, to_apply=%add
-}
-%body.1 (p: bf16[16,512]) -> bf16[16,512] {
-  %ag = bf16[16,512]{1,0} all-gather(%y), replica_groups=[128,2]<=[16,8,2]T(1,0,2), dimensions={1}
-  %cp = f32[64]{0} collective-permute(%z), source_target_pairs={{0,256},{256,0}}
-}
-"""
-
-
-def test_parse_collectives_and_nesting():
-    ops, whiles = parse_collectives(SAMPLE_HLO, n_devices=256, pod_size=256)
-    kinds = sorted(o.kind for o in ops)
-    assert kinds == ["all-gather", "all-reduce", "collective-permute"]
-    assert ("body.1", "main.1") in whiles
-    ar = next(o for o in ops if o.kind == "all-reduce")
-    assert ar.group_size == 4 and ar.result_bytes == 4096
-    assert ar.computation == "main.1"
-    ag = next(o for o in ops if o.kind == "all-gather")
-    assert ag.group_size == 2 and ag.computation == "body.1"
-    cp = next(o for o in ops if o.kind == "collective-permute")
-    assert cp.crosses_pod   # 0 <-> 256 crosses the 256-chip pod boundary
-    # trip scaling: body.1 is one level deep
-    scale_by_loops(ops, whiles, [40])
-    assert ag.trips == 40 and ar.trips == 1
-
-
-def test_wire_byte_model():
-    ag = CollectiveOp("all-gather", 1000, 4, False, "c")
-    assert ag.wire_bytes == pytest.approx(750)
-    rs = CollectiveOp("reduce-scatter", 1000, 4, False, "c")
-    assert rs.wire_bytes == pytest.approx(3000)
-    ar = CollectiveOp("all-reduce", 1000, 4, False, "c")
-    assert ar.wire_bytes == pytest.approx(1500)
-    summary = collective_summary([ag, rs, ar])
-    assert summary["wire_bytes_intra_pod"] == pytest.approx(5250)
-    assert summary["n_ops"] == 3
-
-
-def test_iota_groups_pod_crossing():
-    # groups of 2 with stride 256 cross pods ([2,256] transposed)
-    size, crosses = _parse_groups(
-        "replica_groups=[256,2]<=[2,256]T(1,0)", 512, 256)
-    assert size == 2 and crosses
-    size, crosses = _parse_groups(
-        "replica_groups=[32,16]<=[512]", 512, 256)
-    assert size == 16 and not crosses
