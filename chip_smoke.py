@@ -51,6 +51,7 @@ import pathlib
 import sys
 import tempfile
 import time
+from collections import deque
 
 import numpy as np
 
@@ -169,15 +170,24 @@ def fresh(reqs, max_new_tokens=None):
 
 
 def keep_logits(engine):
-    """Make ``engine`` keep its latest decode step's (max_batch, vocab)
-    logits in ``engine.last_logits``, for phases B and C to compare."""
-    decode = engine._decode
+    """Make ``engine`` keep the (max_batch, vocab) logits of the last two
+    decode programs it dispatched, for phases B and C to compare
+    (``step_logits``)."""
+    decode, engine.dispatched = engine._decode, deque(maxlen=2)
 
     def probed(*args):
-        engine.last_logits, caches = decode(*args)
-        return engine.last_logits, caches
+        toks, logits, caches = decode(*args)
+        engine.dispatched.append(logits)
+        return toks, logits, caches
     engine._decode = probed
     return engine
+
+
+def step_logits(engine):
+    """The logits of the step ``engine.step()`` just took: the last
+    program dispatched, or the one before it where the engine has already
+    dispatched the next step ahead of the host."""
+    return engine.dispatched[-2 if engine._ahead is not None else -1]
 
 
 def serve_all(engine, reqs) -> dict:
@@ -205,7 +215,7 @@ def probe_logits(engine, reqs, steps: int) -> list[np.ndarray]:
     out = []
     for _ in range(steps):
         engine.step()
-        out.append(np.asarray(engine.last_logits, np.float32)[slots])
+        out.append(np.asarray(step_logits(engine), np.float32)[slots])
     check(not engine.active, "probe batch did not finish")
     return out
 
@@ -224,7 +234,7 @@ def decode_trace(engine, reqs, at_steps):
         engine.step()
         step += 1
         if step in at_steps:
-            rows = np.asarray(engine.last_logits, np.float32)
+            rows = np.asarray(step_logits(engine), np.float32)
             for r in admitted:
                 seen[id(r)][step] = rows[slot[id(r)]]
     return [(np.concatenate([r.tokens, np.asarray(r.out_tokens)]),

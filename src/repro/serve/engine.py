@@ -15,15 +15,37 @@ only per-request Python is materializing finished requests.
 
 The jitted programs are named ``engine_decode`` and ``engine_prefill``
 (``jit_engine_decode`` and ``jit_engine_prefill`` in a profiler trace and
-in ``repro.obs.compile_counter()``). Admission and the decode step are
-cut into ``repro.obs`` spans, off unless ``obs.enable(True)`` was called:
-``engine.admit`` holds one ``engine.prefill`` (batch, prefill program,
-first tokens to the host) and one ``engine.splice`` (per-row slices and
-cache splices) per prefill group; ``engine.step`` holds
-``engine.decode`` (inputs and the decode dispatch), ``engine.tokens``
-(the argmax and the tokens to the host, where the host waits for the
-chip) and ``engine.account`` (slot accounting). ``Engine.counters``
-counts prefill rows, padding included, and the padding rows.
+in ``repro.obs.compile_counter()``). ``engine_decode`` takes the greedy
+next token (the argmax of its logits) itself and returns it beside the
+logits and the caches, so a step's tokens can feed the next step without
+a trip to the host.
+
+A call of :meth:`Engine.step` gives every active row one token, on the
+host. Where every cache leaf is written at a row's position (every block
+attends; no SSM state is advanced in place), the engine runs one step
+ahead of the host: before it waits for step k's tokens it dispatches step
+k+1 on them. The host knows without those tokens which rows finish at
+step k (a row finishes at its token budget), so step k+1 runs with those
+rows on the null page, where it can write into no page the next admission
+may hand out. The next call takes step k+1 as its step, unless an
+admission changed the batch in between: then that step's tokens are
+dropped and step k+1 is dispatched again over the new batch, which writes
+each old row's cache at the same position before any layer reads it.
+Engines with SSM state step synchronously.
+``counters["decode_ahead"]`` counts steps dispatched before the previous
+step's tokens reached the host, ``counters["decode_redone"]`` those of
+them dropped for an admission.
+
+Admission and the decode step are cut into ``repro.obs`` spans, off
+unless ``obs.enable(True)`` was called: ``engine.admit`` holds one
+``engine.prefill`` (batch, prefill program, first tokens to the host)
+and one ``engine.splice`` (per-row slices and cache splices) per prefill
+group; ``engine.step`` holds ``engine.decode`` (the inputs and the
+dispatch of the step, where not already in flight, and of the step run
+ahead), ``engine.tokens`` (the step's tokens to the host, where the host
+waits for the chip) and ``engine.account`` (slot accounting).
+``Engine.counters`` also counts prefill rows, padding included, and the
+padding rows.
 
 The engine holds its weights in the layout its decode program reads. At
 construction it lowers ``engine_decode`` with the parameters' layouts
@@ -69,6 +91,25 @@ class Request:
     out_tokens: list = field(default_factory=list)
     done: bool = False
     rejected: bool = False        # oversize for the cache: never admitted
+
+
+def decode_program(lm: LM, rt: Runtime, paged: bool):
+    """The engine's decode step ``engine_decode(params, tokens, lengths,
+    caches[, page_table])``: each row's last token ((B,) or (B, ncb)
+    int32) written at its length. Returns the greedy next tokens, in the
+    shape of ``tokens``, the logits they were taken from, and the updated
+    caches."""
+    def greedy(logits, caches):
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32), logits, caches
+
+    if not paged:
+        def engine_decode(p, t, l, c):
+            return greedy(*lm.decode(p, rt, t[:, None], l, c))
+    else:
+        def engine_decode(p, t, l, c, pt):
+            return greedy(*lm.decode(p, rt, t[:, None], l, c,
+                                     page_table=pt))
+    return engine_decode
 
 
 def decode_formats(engine_decode, params, *args):
@@ -128,20 +169,15 @@ class Engine:
         self.lm, self.rt = lm, rt
         self.max_batch, self.max_len = max_batch, max_len
         self.page_size = page_size
-        self.lengths = jnp.zeros((max_batch,), jnp.int32)
+        self.lengths = np.zeros((max_batch,), np.int32)
         self.active: dict[int, Request] = {}     # slot -> request
         self.free = list(range(max_batch))
         ncb = lm.cfg.n_codebooks
         tok_shape = (max_batch,) if ncb <= 1 else (max_batch, ncb)
-        step_toks = jax.ShapeDtypeStruct(tok_shape[:1] + (1,) + tok_shape[1:],
-                                         jnp.int32)
         if page_size is None:
             self.pager = None
             self.caches = lm.init_cache(max_batch, max_len)
             tables = ()
-
-            def engine_decode(p, t, l, c):
-                return lm.decode(p, rt, t, l, c)
         else:
             # physical paged KV: attention caches live in one shared page
             # pool; a slot's cache is the pages its table row maps. Page 0
@@ -165,25 +201,37 @@ class Engine:
             self._page_table = np.zeros((max_batch, self.pages_per_slot),
                                         np.int32)
             tables = (self._page_table,)
-
-            def engine_decode(p, t, l, c, pt):
-                return lm.decode(p, rt, t, l, c, page_table=pt)
+        engine_decode = decode_program(lm, rt, paged=page_size is not None)
         # the weights, once, in the layout the decode program reads; the
         # prefill programs are compiled against it too
-        self.formats = decode_formats(engine_decode, params, step_toks,
-                                      self.lengths, self.caches, *tables)
+        self.formats = decode_formats(
+            engine_decode, params, jax.ShapeDtypeStruct(tok_shape, jnp.int32),
+            self.lengths, self.caches, *tables)
         self.params = hold_in(params, self.formats)
         self._decode = jax.jit(
             engine_decode, in_shardings=(self.formats, None, None, None)
             + (None,) * len(tables), donate_argnums=(3,))
         self._prefill = {}
         self.steps = 0
+        # a step dispatched ahead of the host can be dropped and run again
+        # exactly only where each cache leaf is written at a row's position
+        # (attention K/V), none advanced in place (SSM state)
+        self._run_ahead = all(lm.cfg.block_kind(i) == "attn"
+                              for i in range(lm.cfg.pattern_period))
+        self._ahead = None      # the step in flight: its tokens, on device
+        # the host's tokens go on the device where the step leaves its
+        # own, so that the program sees one kind of token input and
+        # compiles once
+        self._tok_sharding = jax.tree.leaves(self.params)[0].sharding
         # rows run through prefill, and of them the padding rows that
         # repeat a group's last request to fill ``prefill_chunk``; bytes of
-        # weights held in a layout other than the one they came in
+        # weights held in a layout other than the one they came in; steps
+        # dispatched before the previous step's tokens reached the host,
+        # and of them those dropped because an admission changed the batch
         self.counters = {"prefill_rows": 0, "prefill_rows_padded": 0,
                          "weights_relaid_bytes": relaid_bytes(params,
-                                                              self.formats)}
+                                                              self.formats),
+                         "decode_ahead": 0, "decode_redone": 0}
         # ---- vectorized slot accounting ----
         self._active_mask = np.zeros((max_batch,), bool)
         self._last_tok = np.zeros(tok_shape, np.int32)
@@ -303,6 +351,11 @@ class Engine:
                 groups.setdefault((plen, req.patches is not None),
                                   []).append((slot, req))
                 admitted.append(req)
+            if admitted and self._ahead is not None:
+                # the step run ahead left the new rows out: the next step
+                # dispatches it again over the new batch
+                self._ahead = None
+                self.counters["decode_redone"] += 1
             step = self.prefill_chunk
             for (plen, has_patches), members in groups.items():
                 for i0 in range(0, len(members), step or len(members)):
@@ -346,7 +399,7 @@ class Engine:
         for i, (slot, req) in enumerate(members):
             self.active[slot] = req
             req.out_tokens.append(toks[i])
-        self.lengths = self.lengths.at[slots].set(plen + n_img)
+        self.lengths[slots] = plen + n_img
         self._last_tok[slots] = toks
         self._out_buf[slots, 0] = toks
         self._out_len[slots] = 1
@@ -359,46 +412,65 @@ class Engine:
 
     # ----------------------------------------------------------- decode
     def step(self) -> list[Request]:
-        """One decode step for all active slots; returns finished requests."""
+        """One decode step for all active slots: one token each, on the
+        host. Returns the requests that finished."""
         if not self.active:
             return []
         with obs.span("engine.step"):
             with obs.span("engine.decode"):
-                logits = self._dispatch_decode()
+                toks = self._ahead
+                if toks is None:
+                    toks = self._dispatch(jax.device_put(
+                        self._last_tok.copy(), self._tok_sharding))
+                stepped, done = self._advance()
+                self._ahead = None
+                if self._run_ahead and self._active_mask.any():
+                    self._ahead = self._dispatch(toks)
+                    self.counters["decode_ahead"] += 1
             with obs.span("engine.tokens"):
                 # (B,) or (B, ncb), on the host: waits for the step
-                nxt = np.asarray(jnp.argmax(logits, axis=-1))
+                nxt = np.asarray(toks)
             with obs.span("engine.account"):
-                return self._account(nxt)
+                return self._account(nxt, stepped, done)
 
-    def _dispatch_decode(self):
-        """The decode program on every slot's last token; returns its
-        logits, still on the device, and keeps the updated caches."""
-        ncb = self.lm.cfg.n_codebooks
-        toks = (self._last_tok[:, None] if ncb <= 1
-                else self._last_tok[:, None, :])
-        if self.pager is None:
-            logits, self.caches = self._decode(
-                self.params, jnp.asarray(toks), self.lengths, self.caches)
-        else:
-            logits, self.caches = self._decode(
-                self.params, jnp.asarray(toks), self.lengths, self.caches,
-                jnp.asarray(self._page_table))
-        return logits
+    def _dispatch(self, toks):
+        """The decode program on ``toks``, every slot's last token on the
+        device, at the host's lengths and page table. Keeps the updated
+        caches; returns the step's tokens, still on the device. The host's
+        arrays go in as copies: it changes its own while the step may
+        still be queued."""
+        tables = () if self.pager is None else (self._page_table.copy(),)
+        toks, _, self.caches = self._decode(
+            self.params, toks, self.lengths.copy(), self.caches, *tables)
+        return toks
 
-    def _account(self, nxt: np.ndarray) -> list[Request]:
-        """Append the step's tokens ``nxt`` to the active slots, advance
-        their lengths, and free and return the requests that finished."""
-        mask = self._active_mask
-        self._last_tok[mask] = nxt[mask]
-        self._out_buf[mask, self._out_len[mask]] = nxt[mask]
-        self._out_len[mask] += 1
-        self.lengths = self.lengths + jnp.asarray(mask.astype(np.int32))
-        self.steps += 1
-        done = np.nonzero(mask & (self._out_len >= self._budget))[0]
+    def _advance(self):
+        """What the host knows of a dispatched step before its tokens
+        arrive: every active slot gets one token and one position, and a
+        slot that reaches its budget finishes and goes back to the null
+        page. Returns the slots stepped (a mask) and those finished, in
+        admission order."""
+        stepped = self._active_mask.copy()
+        self._out_len[stepped] += 1
+        self.lengths += stepped
+        done = np.nonzero(stepped & (self._out_len >= self._budget))[0]
         # finish in admission order: the env observes completions in the
         # same order a per-slot event queue would deliver them
         done = done[np.argsort(self._admit_seq[done], kind="stable")]
+        self._active_mask[done] = False
+        if self.pager is not None:
+            for slot in done:
+                self.pager.free(int(slot))
+            self._page_table[done] = 0
+        return stepped, done
+
+    def _account(self, nxt: np.ndarray, stepped: np.ndarray,
+                 done: np.ndarray) -> list[Request]:
+        """Append the step's tokens ``nxt`` to the ``stepped`` slots, and
+        free and return the requests of the ``done`` slots."""
+        self._last_tok[stepped] = nxt[stepped]
+        self._out_buf[stepped, self._out_len[stepped] - 1] = nxt[stepped]
+        self.steps += 1
         finished = []
         for slot in (int(s) for s in done):
             req = self.active.pop(slot)
@@ -406,10 +478,6 @@ class Engine:
             # materialize the slot's output buffer (admit wrote index 0)
             req.out_tokens = [self._out_buf[slot, i]
                               for i in range(int(self._out_len[slot]))]
-            self._active_mask[slot] = False
-            if self.pager is not None:
-                self.pager.free(slot)
-                self._page_table[slot] = 0   # back to the null page
             self.free.append(slot)
             finished.append(req)
         return finished

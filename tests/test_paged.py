@@ -312,14 +312,17 @@ def _scan_output_lm(cfg):
 
 
 def _recorded(engine):
-    """Keep every decode step's logits and caches, copied to the host
-    before the next step donates them."""
+    """Keep every decode step's tokens, logits and caches, copied to the
+    host before the next step donates them; each step's tokens are the
+    argmax of its logits."""
     decode, engine.record = engine._decode, []
 
     def probed(*args):
-        logits, caches = decode(*args)
-        engine.record.append(jax.tree.map(np.asarray, (logits, caches)))
-        return logits, caches
+        out = decode(*args)
+        toks, logits, _ = rec = jax.tree.map(np.asarray, out)
+        np.testing.assert_array_equal(toks, np.argmax(logits, axis=-1))
+        engine.record.append(rec)
+        return out
     engine._decode = probed
     return engine
 
@@ -335,7 +338,9 @@ def test_in_place_decode_bitwise_matches_scan_output_reference(
     bit for bit, as the step that stacks every layer's caches as a scan
     output; over decode steps that cross page boundaries and a second
     admission into a freed slot. ``kv_shard="seq"`` takes the
-    sequence-sharded attention, here on one device."""
+    sequence-sharded attention, here on one device. Attention-only
+    models run one step ahead, so the admission drops one step and
+    dispatches it again; models with SSM state step synchronously."""
     from repro.configs import get_smoke_config
     from repro.configs.base import ParallelConfig
     from repro.models.lm import LM
@@ -363,10 +368,11 @@ def test_in_place_decode_bitwise_matches_scan_output_reference(
         eng.admit_many([req(3, 7, 5), req(4, 5, 4)])
         while eng.active:
             eng.step()
+        assert eng.counters["decode_redone"] == (0 if cfg.ssm else 1)
         return eng.record
 
     got, want = serve(LM(cfg)), serve(_scan_output_lm(cfg))
-    assert len(got) == len(want) == 7
+    assert len(got) == len(want) == (7 if cfg.ssm else 8)
     for step, (g, w) in enumerate(zip(got, want)):
         assert jax.tree.structure(g) == jax.tree.structure(w)
         for a, b in zip(jax.tree.leaves(g), jax.tree.leaves(w)):

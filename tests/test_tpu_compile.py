@@ -25,7 +25,7 @@ import pytest
 from repro.configs.granite_3_8b import CONFIG as GRANITE
 from repro.configs.mamba2_1_3b import CONFIG as MAMBA2
 from repro.models.lm import LM
-from repro.serve.engine import decode_formats, relaid_bytes
+from repro.serve.engine import decode_formats, decode_program, relaid_bytes
 
 #: granite-3-8b at its published widths; 2 of its 40 layers keep each
 #: engine compile to seconds (the scanned layer body is the same program)
@@ -145,19 +145,19 @@ class Decode(NamedTuple):
 
 @pytest.fixture(scope="module")
 def decode(granite, spec):
-    """Per cache kind, the decode step compiled as the engine compiles it:
-    the formats the compiler chooses for the parameters, then the program
-    against them; beside it the program with every default layout."""
+    """Per cache kind, the engine's decode step, its greedy tokens taken
+    inside it, compiled as the engine compiles it: the formats the
+    compiler chooses for the parameters, then the program against them;
+    beside it the program with every default layout."""
     lm, rt, params = granite
-    toks, lengths = spec((SLOTS, 1), I32), spec((SLOTS,), I32)
+    toks, lengths = spec((SLOTS,), I32), spec((SLOTS,), I32)
     cache = spec(lm.cache_shapes(SLOTS, MAX_LEN))
     pool = spec(lm.paged_cache_shapes(SLOTS, 1 + SLOTS * MAX_LEN // PAGE,
                                       PAGE))
     kinds = {
-        "contiguous": (lambda p, t, l, c: lm.decode(p, rt, t, l, c),
+        "contiguous": (decode_program(lm, rt, paged=False),
                        (toks, lengths, cache)),
-        "paged": (lambda p, t, l, c, pt: lm.decode(p, rt, t, l, c,
-                                                   page_table=pt),
+        "paged": (decode_program(lm, rt, paged=True),
                   (toks, lengths, pool, spec((SLOTS, MAX_LEN // PAGE), I32))),
     }
     out = {}
@@ -197,12 +197,18 @@ def _check_in_place(compiled, caches):
     assert not _whole_cache_writes(compiled, caches)
 
 
+def _check_outputs(compiled):
+    """The step returns each row's next token beside its logits."""
+    toks, logits = compiled.out_info[:2]
+    assert (toks.shape, toks.dtype) == ((SLOTS,), I32)
+    assert logits.shape == (SLOTS, GRANITE.vocab_padded)
+
+
 def test_engine_contiguous_decode_compiles(decode):
     """The contiguous step writes one token per row and layer into the
     donated cache, in place."""
     c = decode["contiguous"]
-    logits = c.engine.out_info[0]
-    assert logits.shape == (SLOTS, GRANITE.vocab_padded)
+    _check_outputs(c.engine)
     _check_in_place(c.engine, c.caches)
 
 
@@ -210,6 +216,7 @@ def test_engine_paged_decode_compiles_within_memory(decode):
     """The paged step scatters one token per row and layer into the
     donated page pool, in place."""
     c = decode["paged"]
+    _check_outputs(c.engine)
     _check_in_place(c.engine, c.caches)
 
 
